@@ -29,6 +29,18 @@ def resolve_interpret(interpret: bool | None) -> bool:
     return bool(interpret)
 
 
+def compiler_params(interpret: bool, *semantics: str) -> dict:
+    """``pallas_call`` keyword arguments for the compiled TPU path: the
+    grid's ``dimension_semantics`` (one entry per grid axis, "parallel" or
+    "arbitrary").  Interpret mode takes no compiler params, so it gets
+    none."""
+    if interpret:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=semantics)}
+
+
 def bucket_pad(n: int, block: int) -> int:
     """Pad ``n`` up to ``block`` granularity, then bucket the block count to
     the next power of two.

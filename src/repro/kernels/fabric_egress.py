@@ -10,22 +10,23 @@ consecutive rows that repeat its shard arrays with per-tenant permbits
 
   * each row carries one host's resident table shard (see
     `repro.core.fabric.HostRuntime`) in the stacked ``[R, N]`` entry
-    arrays, so grid step ``(h, j)`` loads row ``h``'s shard into VMEM and
-    evaluates the same adaptive cover search as the single-host kernel
-    (`_cover_search` is shared code);
-  * the tenant HWPID is a *dynamic* per-row operand (``hwpids[h]``) rather
-    than the single-host kernel's static argument — one compiled kernel
-    serves every (host, tenant) pair in the fleet, and admitting a tenant
-    with a fresh HWPID does not recompile;
+    arrays, so grid step ``(h, j)`` has row ``h``'s shard in SMEM and
+    evaluates the same adaptive search as the single-host kernel
+    (`memcrypt.checked_release` is shared code);
+  * the tenant HWPID is a *dynamic* per-row operand (``hwpids[h]``, scalar
+    prefetch) rather than the single-host kernel's static argument — one
+    compiled kernel serves every (host, tenant) pair in the fleet, and
+    admitting a tenant with a fresh HWPID does not recompile;
   * rows are fully independent: revoking one tenant re-derives only that
     tenant's permbits rows, and its lanes zero out while a co-resident
     tenant's rows — same host, same shard arrays — are untouched (pinned
     bit-exactly by the multi-tenant oracle test in tests/test_fabric.py);
   * flat-vs-hier selection is *per row*: the wrapper scores every row's
     batch against that row's shard summary (`summary_candidate_tiles`
-    vectorized over rows) and ships a ``use_hier i32[R]`` operand — a host
-    serving uniform traffic runs the flat scan while its neighbor with a
-    hot working set keeps the two-level win, in the same launch;
+    vectorized over rows) and ships a ``use_hier i32[R]`` scalar-prefetch
+    operand — a host serving uniform traffic runs the flat scan while its
+    neighbor with a hot working set keeps the two-level win, in the same
+    launch;
   * each grid step streams SUPER_BLOCKS x BLOCK words (double-buffered
     across steps on TPU via ``dimension_semantics``), and the keystream
     counter stays the flat word position ``h * padded_B + j * sb + lane`` —
@@ -44,56 +45,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.checker import (
-    FAULT_NO_ABITS,
-    FAULT_NO_ENTRY,
-    FAULT_NONE,
-    FAULT_NOT_LOCAL,
-    FAULT_PERM,
-)
-from repro.core.crypto import arx_mac32
-from repro.core.table import HWPID_SHIFT, PAGE_MASK
-from repro.kernels import bucket_pad, resolve_interpret
-from repro.kernels.memcrypt import BLOCK, SUPER_BLOCKS, _keystream
-from repro.kernels.permcheck import (ENTRY_TILE, HIER_DENSITY_DEN,
-                                     HIER_DENSITY_NUM, _cover_search,
+from repro.core.table import PAGE_MASK
+from repro.kernels import bucket_pad, compiler_params, resolve_interpret
+from repro.kernels.memcrypt import BLOCK, SUPER_BLOCKS, checked_release
+from repro.kernels.permcheck import (HIER_DENSITY_DEN, HIER_DENSITY_NUM,
                                      grant_sizes)
 
 
-def _fabric_egress_kernel(data_ref, addr_ref, hwpid_ref, sel_ref, starts_ref,
+def _fabric_egress_kernel(hwpid_ref, sel_ref, data_ref, addr_ref, starts_ref,
                           sizes_ref, sizes_ok_ref, tmin_ref, tmax_ref,
-                          out_ref, fault_ref, *, key0: int,
-                          key1: int, n_entries: int, n_steps: int,
-                          rows: int):
+                          out_ref, fault_ref, *, key0: int, key1: int,
+                          n_tiles: int, n_steps: int):
     h = pl.program_id(0)
     j = pl.program_id(1)
-    d = data_ref[...].reshape(rows, 128)
-    ext = addr_ref[...].astype(jnp.int32).reshape(rows, 128)
-    hwpid = hwpid_ref[h]                       # dynamic per-host tenant tag
-    tag = ext >> HWPID_SHIFT
-    page = ext & PAGE_MASK
-    tag_ok = tag == hwpid
-
-    any_ok, covered = _cover_search(
-        page,
-        starts_ref[...].reshape(-1), sizes_ref[...].reshape(-1),
-        sizes_ok_ref[...].reshape(-1),
-        tmin_ref[...].reshape(-1), tmax_ref[...].reshape(-1),
-        n_entries // ENTRY_TILE,
-        sel_ref[h] > 0)                        # per-host adaptive selection
-
-    allowed = tag_ok & any_ok
-    fault = jnp.where(
-        allowed, FAULT_NONE,
-        jnp.where(tag <= 0, FAULT_NO_ABITS,
-                  jnp.where(~tag_ok, FAULT_NOT_LOCAL,
-                            jnp.where(~covered, FAULT_NO_ENTRY, FAULT_PERM))))
-
-    line, word = _keystream(h * n_steps + j, 0, rows)
-    ks0, _ = arx_mac32(jnp.uint32(key0), jnp.uint32(key1), line, word)
-    out = jnp.where(allowed, d ^ ks0, jnp.uint32(0))
-    out_ref[...] = out.reshape(out_ref.shape)
-    fault_ref[...] = fault.astype(jnp.int32).reshape(fault_ref.shape)
+    out, fault = checked_release(
+        data_ref[...], addr_ref[...],
+        hwpid_ref[h],                          # dynamic per-row tenant tag
+        (starts_ref, sizes_ref, sizes_ok_ref), (tmin_ref, tmax_ref), n_tiles,
+        sel_ref[h] > 0,                        # per-row adaptive selection
+        h * n_steps + j, key0=key0, key1=key1, base_word=0)
+    out_ref[...] = out
+    fault_ref[...] = fault
 
 
 def _per_host_use_hier(pages, tmin, tmax, *, block: int):
@@ -134,36 +106,40 @@ def _fabric_egress_impl(data, ext, hwpids, starts, ends, permbits, tmin,
     sizes, sizes_ok = grant_sizes(starts, ends, permbits, jnp.uint32(need))
     sel = _per_host_use_hier(extp & PAGE_MASK, tmin, tmax, block=sb)
 
+    # words as (rows, 128) tiles, the row dimension squeezed out of the
+    # block; each row's shard arrays as (1, N) SMEM rows, single-buffered
+    # (they change only when the grid moves to the next row)
+    words = pl.BlockSpec((None, rows, 128), lambda i, j, *_: (i, j, 0))
+
+    def shard_row(n):
+        return pl.BlockSpec((None, 1, n), lambda i, j, *_: (i, 0, 0),
+                            memory_space=pltpu.SMEM,
+                            pipeline_mode=pl.Buffered(1))
+
     kernel = functools.partial(
         _fabric_egress_kernel, key0=int(key0), key1=int(key1),
-        n_entries=np_, n_steps=n_steps, rows=rows)
+        n_tiles=n_tiles, n_steps=n_steps)
     out, fault = pl.pallas_call(
         kernel,
-        grid=(h, n_steps),
-        in_specs=[
-            pl.BlockSpec((1, sb), lambda i, j: (i, j)),
-            pl.BlockSpec((1, sb), lambda i, j: (i, j)),
-            pl.BlockSpec((h,), lambda i, j: (0,)),
-            pl.BlockSpec((h,), lambda i, j: (0,)),
-            pl.BlockSpec((1, np_), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, np_), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, np_), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, n_tiles), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, n_tiles), lambda i, j: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, sb), lambda i, j: (i, j)),
-            pl.BlockSpec((1, sb), lambda i, j: (i, j)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,             # hwpids, sel
+            grid=(h, n_steps),
+            in_specs=[words, words] + [shard_row(np_)] * 3
+            + [shard_row(n_tiles)] * 2,
+            out_specs=[words, words],
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct((h, bp), jnp.uint32),
-            jax.ShapeDtypeStruct((h, bp), jnp.int32),
+            jax.ShapeDtypeStruct((h, bp // 128, 128), jnp.uint32),
+            jax.ShapeDtypeStruct((h, bp // 128, 128), jnp.int32),
         ],
         interpret=interpret,
-        **({} if interpret else {"compiler_params": pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel"))}),
-    )(buf, extp, jnp.asarray(hwpids, jnp.int32), sel, starts, sizes,
-      sizes_ok, tmin, tmax)
+        **compiler_params(interpret, "parallel", "parallel"),
+    )(jnp.asarray(hwpids, jnp.int32), sel,
+      buf.reshape(h, bp // 128, 128), extp.reshape(h, bp // 128, 128),
+      *(jnp.asarray(a, jnp.int32)[:, None, :]
+        for a in (starts, sizes, sizes_ok, tmin, tmax)))
+    out = out.reshape(h, bp)
+    fault = fault.reshape(h, bp)
     return out[:, :b], fault[:, :b]
 
 

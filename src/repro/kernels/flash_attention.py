@@ -32,7 +32,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import resolve_interpret
+from repro.kernels import compiler_params, resolve_interpret
 
 NEG_INF = -0.7 * float(np.finfo(np.float32).max)
 
@@ -139,8 +139,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = -1,
         # K is innermost and sequential (scratch accumulates across it);
         # batch/head/Q-block steps are independent, so Mosaic may double-
         # buffer and reorder them.
-        **({} if interpret else {"compiler_params": pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))}),
+        **compiler_params(interpret, "parallel", "parallel", "parallel",
+                          "arbitrary"),
     )(q, k, v)
     return out[:, :, :sq]

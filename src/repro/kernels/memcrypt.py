@@ -22,9 +22,9 @@ of one BLOCK: the summary pass, candidate-tile walk, and per-step dispatch
 are amortized over 8x the lanes, and on TPU the multi-block grid is marked
 ``dimension_semantics=("parallel",)`` so Mosaic double-buffers the operand
 stream — block j+1's HBM->VMEM copy overlaps block j's check+keystream
-compute.  It also drops the matched-entry argmax the generic permcheck
-kernel pays (the egress path needs allow/cover bits, never the index); see
-``permcheck._cover_search``.
+compute.  The check is `permcheck.search`, shared with the permcheck and
+fabric kernels; `checked_release` turns its verdict into fault codes and
+the released words.
 """
 from __future__ import annotations
 
@@ -45,10 +45,9 @@ from repro.core.checker import (
 )
 from repro.core.crypto import arx_mac32
 from repro.core.table import HWPID_SHIFT, PAGE_MASK
-from repro.kernels import bucket_pad, resolve_interpret
-from repro.kernels.permcheck import (ENTRY_TILE, ShardView, _cover_search,
-                                     grant_sizes, hier_profitable,
-                                     make_shard_view)
+from repro.kernels import bucket_pad, compiler_params, resolve_interpret
+from repro.kernels.permcheck import (ShardView, grant_sizes, hier_profitable,
+                                     make_shard_view, search, smem_rows)
 
 BLOCK = 1024        # u32 words per grid step, viewed (8, 128)
 SUPER_BLOCKS = 8    # BLOCK-tiles streamed per fused-egress grid step
@@ -100,8 +99,7 @@ def memcrypt_pallas(data, *, key0: int, key1: int, base_word: int = 0,
         interpret=interpret,
         # every BLOCK of the keystream is independent — let Mosaic double-
         # buffer the stream
-        **({} if interpret else {"compiler_params": pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel",))}),
+        **compiler_params(interpret, "parallel"),
     )(buf)
     return out[:n].reshape(d.shape)
 
@@ -110,36 +108,40 @@ def memcrypt_pallas(data, *, key0: int, key1: int, base_word: int = 0,
 # Fused permission check ⊕ memcrypt egress kernel
 # ---------------------------------------------------------------------------
 
-def _checked_memcrypt_kernel(data_ref, addr_ref, sel_ref, starts_ref,
-                             sizes_ref, sizes_ok_ref, tmin_ref, tmax_ref,
-                             out_ref, fault_ref, *, hwpid: int,
-                             key0: int, key1: int, base_word: int,
-                             n_entries: int, rows: int):
-    i = pl.program_id(0)
-    d = data_ref[...].reshape(rows, 128)
-    ext = addr_ref[...].astype(jnp.int32).reshape(rows, 128)
+def checked_release(d, ext, hwpid, ent, summary, n_tiles: int, use_hier,
+                    step, *, key0: int, key1: int, base_word: int):
+    """Check ⊕ decrypt of one (rows, 128) block, shared by the single-host
+    and the fabric-batched kernels: `search` for the verdict, FAULT_* codes
+    on denied lanes, and the keystream XOR for grid step ``step``.
+    Response-side enforcement: denied lanes read as zero.  Returns
+    (out u32, fault i32)."""
     tag = ext >> HWPID_SHIFT
     page = ext & PAGE_MASK
-    tag_ok = tag == jnp.int32(hwpid)
-
-    any_ok, covered = _cover_search(
-        page, starts_ref[...], sizes_ref[...], sizes_ok_ref[...],
-        tmin_ref[...], tmax_ref[...], n_entries // ENTRY_TILE,
-        sel_ref[0] > 0)
-
+    tag_ok = tag == hwpid
+    any_ok, idx = search(page, ent, summary, n_tiles, use_hier)
     allowed = tag_ok & any_ok
     fault = jnp.where(
         allowed, FAULT_NONE,
         jnp.where(tag <= 0, FAULT_NO_ABITS,
                   jnp.where(~tag_ok, FAULT_NOT_LOCAL,
-                            jnp.where(~covered, FAULT_NO_ENTRY, FAULT_PERM))))
-
-    # Keystream XOR, response-side enforcement: denied lanes read as zero.
-    line, word = _keystream(i, base_word, rows)
+                            jnp.where(idx < 0, FAULT_NO_ENTRY, FAULT_PERM))))
+    line, word = _keystream(step, base_word, d.shape[0])
     ks0, _ = arx_mac32(jnp.uint32(key0), jnp.uint32(key1), line, word)
-    out = jnp.where(allowed, d ^ ks0, jnp.uint32(0))
+    return jnp.where(allowed, d ^ ks0, jnp.uint32(0)), fault.astype(jnp.int32)
+
+
+def _checked_memcrypt_kernel(data_ref, addr_ref, sel_ref, starts_ref,
+                             sizes_ref, sizes_ok_ref, tmin_ref, tmax_ref,
+                             out_ref, fault_ref, *, hwpid: int,
+                             key0: int, key1: int, base_word: int,
+                             n_tiles: int, rows: int):
+    out, fault = checked_release(
+        data_ref[...].reshape(rows, 128), addr_ref[...].reshape(rows, 128),
+        jnp.int32(hwpid), (starts_ref, sizes_ref, sizes_ok_ref),
+        (tmin_ref, tmax_ref), n_tiles, sel_ref[0, 0] > 0, pl.program_id(0),
+        key0=key0, key1=key1, base_word=base_word)
     out_ref[...] = out.reshape(out_ref.shape)
-    fault_ref[...] = fault.astype(jnp.int32).reshape(fault_ref.shape)
+    fault_ref[...] = fault.reshape(fault_ref.shape)
 
 
 @functools.partial(jax.jit,
@@ -180,41 +182,28 @@ def checked_memcrypt_view_pallas(data, ext_addrs, view: ShardView, *,
     buf = jnp.zeros((bp,), jnp.uint32).at[:b].set(d)
     ext = jnp.full((bp,), -1, jnp.int32).at[:b].set(
         jnp.asarray(ext_addrs, jnp.int32))
-    s = view.starts
-    sz, szok = grant_sizes(s, view.ends, view.permbits, jnp.uint32(need))
-    tmin, tmax = view.tile_min, view.tile_max
-    np_ = s.shape[0]
-    n_tiles = view.n_tiles
-    sel = hier_profitable(ext, tmin, tmax, block=sb)
-
+    sz, szok = grant_sizes(view.starts, view.ends, view.permbits,
+                           jnp.uint32(need))
+    sel = hier_profitable(ext, view.tile_min, view.tile_max, block=sb)
+    block = pl.BlockSpec((sb,), lambda i: (i,))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     kernel = functools.partial(
         _checked_memcrypt_kernel, hwpid=hwpid, key0=int(key0),
-        key1=int(key1), base_word=int(base_word), n_entries=np_, rows=rows)
+        key1=int(key1), base_word=int(base_word), n_tiles=view.n_tiles,
+        rows=rows)
     out, fault = pl.pallas_call(
         kernel,
         grid=(bp // sb,),
-        in_specs=[
-            pl.BlockSpec((sb,), lambda i: (i,)),
-            pl.BlockSpec((sb,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((np_,), lambda i: (0,)),
-            pl.BlockSpec((np_,), lambda i: (0,)),
-            pl.BlockSpec((np_,), lambda i: (0,)),
-            pl.BlockSpec((n_tiles,), lambda i: (0,)),
-            pl.BlockSpec((n_tiles,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((sb,), lambda i: (i,)),
-            pl.BlockSpec((sb,), lambda i: (i,)),
-        ],
+        in_specs=[block, block] + [smem] * 6,
+        out_specs=[block, block],
         out_shape=[
             jax.ShapeDtypeStruct((bp,), jnp.uint32),
             jax.ShapeDtypeStruct((bp,), jnp.int32),
         ],
         interpret=interpret,
-        **({} if interpret else {"compiler_params": pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel",))}),
-    )(buf, ext, sel.astype(jnp.int32).reshape(1), s, sz, szok, tmin, tmax)
+        **compiler_params(interpret, "parallel"),
+    )(buf, ext, sel.astype(jnp.int32).reshape(1, 1),
+      *smem_rows(view.starts, sz, szok, view.tile_min, view.tile_max))
     return out[:b], fault[:b]
 
 

@@ -2,21 +2,23 @@
 
 `use_pallas` selects the Pallas path (auto backend: compiled on TPU,
 interpret elsewhere — see ``repro.kernels.resolve_interpret``); the default
-falls back to the pure-jnp reference (ref.py), which is what the dry-run
-lowers so the 512-device host meshes never see Pallas primitives.
+is the pure-jnp reference (ref.py), which is what the dry-run lowers so the
+512-device host meshes never see Pallas primitives.  A table the kernels
+cannot hold (more than ``permcheck.MAX_ENTRIES`` entries) raises on the
+Pallas path; it is never answered by the reference instead.
 """
 from __future__ import annotations
 
 from . import ref
 from .memcrypt import checked_memcrypt_pallas, memcrypt_pallas
-from .permcheck import MAX_ENTRIES, permcheck_pallas
+from .permcheck import permcheck_pallas
 
 
 def permission_check(ext_addrs, starts, ends, permbits, *, hwpid: int,
                      need: int, use_pallas: bool = False,
                      mode: str = "hier"):
     """(allowed bool[B], idx i32[B]) — see kernels/permcheck.py."""
-    if use_pallas and starts.shape[0] <= MAX_ENTRIES:
+    if use_pallas:
         return permcheck_pallas(ext_addrs, starts, ends, permbits,
                                 hwpid=hwpid, need=need, mode=mode)
     return ref.permcheck(ext_addrs, starts, ends, permbits,
@@ -44,7 +46,7 @@ def checked_memory_decrypt(data, ext_addrs, starts, ends, permbits, *,
     See kernels/memcrypt.py (`checked_memcrypt_pallas`) and the matching
     oracle `ref.checked_memcrypt`.
     """
-    if use_pallas and starts.shape[0] <= MAX_ENTRIES:
+    if use_pallas:
         return checked_memcrypt_pallas(data, ext_addrs, starts, ends,
                                        permbits, hwpid=hwpid, need=need,
                                        key0=key0, key1=key1,

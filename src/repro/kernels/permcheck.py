@@ -2,15 +2,16 @@
 
 TPU-native rethinking of the paper's binary-search checker (DESIGN.md §7):
 instead of log2(N) serialized DRAM probes per access (the CPU/CXL cost
-structure), the sorted table shard lives in VMEM and the VPU evaluates the
-range/permission predicate for an (8, 128) block of tagged addresses.  VMEM
-residency plays the role of the paper's permission cache: the table is loaded
-from HBM once per grid row, not per access.
+structure), the sorted table shard sits in SMEM and the VPU evaluates the
+range/permission predicate for an (8, 128) block of tagged addresses, one
+entry per step of a scalar loop.  On-chip residency plays the role of the
+paper's permission cache: the table is loaded from HBM once per call, not
+per access.
 
-Three kernel variants share the wrapper:
+Three search modes share one kernel (see `search`):
 
   mode="adaptive" (default) — batch-aware selection between the two fixed
-    kernels below.  The wrapper estimates the batch's candidate-tile density
+    modes below.  The wrapper estimates the batch's candidate-tile density
     from the tile summary it already holds (`summary_candidate_tiles`) and
     passes the verdict into the kernel as a scalar operand: dense batches
     (uniform traces, where the hierarchical summary scan is pure overhead)
@@ -20,32 +21,31 @@ Three kernel variants share the wrapper:
 
   mode="hier" — two-level hierarchical search.  A precomputed per-tile
     summary (min-start / max-end per ENTRY_TILE consecutive entries, see
-    ``repro.core.table.tile_summary``) is scanned first: a cheap
-    (R, 128, n_tiles) predicate finds each address's candidate tile, and the
-    expensive (R, 128, ENTRY_TILE) range/permission evaluation runs only for
-    tiles some lane actually needs (``lax.cond``-skipped otherwise).  Inner
-    work drops from O(N) to O(N/ENTRY_TILE + k·ENTRY_TILE) per block, where k
-    is the number of distinct candidate tiles — 1-2 for the locality-heavy
-    access patterns the paper's 16 KiB cache exploits.
+    ``repro.core.table.tile_summary``) is tested first, and the tile's
+    ENTRY_TILE entries are folded only when some lane of the block falls
+    in its window (``lax.cond``-skipped otherwise).  Work drops from O(N)
+    to O(N/ENTRY_TILE + k·ENTRY_TILE) per block, where k is the number of
+    candidate tiles — 1-2 for the locality-heavy access patterns the
+    paper's 16 KiB cache exploits.
 
-  mode="flat" — the original brute-force O(B·N) scan: the baseline for
-    benchmarks/kernels_bench.py, and the better kernel when nearly every
+  mode="flat" — the brute-force O(B·N) scan: the baseline for
+    benchmarks/kernels_bench.py, and the better choice when nearly every
     tile is a candidate anyway.
 
 Layout:
   addresses  i32[B]   -> grid-blocked (ADDR_BLOCK,) tiles, viewed (8, 128)
-  starts     i32[N]   -> whole-shard VMEM resident (index_map -> 0)
-  sizes/sizes_ok u32[N] -> diff-form spans (see `grant_sizes`): the range
+  starts     i32[N]   -> whole-shard SMEM row (1, N)
+  sizes/sizes_ok i32[N] -> diff-form spans (see `grant_sizes`): the range
     and permission tests each collapse to one unsigned compare against
     ``(page - start) as u32``, with a denied entry carrying a zero window
-  tile_min/max i32[n_tiles] -> whole-resident summary (hier mode only)
+  tile_min/max i32[n_tiles] -> SMEM rows, the summary
   outputs    allowed u32[B] (0/1), idx i32[B]
 
-N is the *per-shard* entry count.  The two-level search makes large shards
-cheap, so the ceiling is MAX_ENTRIES = 65536 (768 KiB of VMEM for the three
-entry arrays — comfortably resident); the global table is range-partitioned
-across the "model" mesh axis (see repro.launch.sharding), mirroring the
-paper's table-in-SDM with per-host checkers.
+N is the *per-shard* entry count.  The ceiling is MAX_ENTRIES = 65536: the
+three entry rows take 768 KiB of the chip's 1 MiB of SMEM.  The global
+table is range-partitioned across the "model" mesh axis (see
+repro.launch.sharding), mirroring the paper's table-in-SDM with per-host
+checkers.
 """
 from __future__ import annotations
 
@@ -61,11 +61,12 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.table import (HWPID_SHIFT, PAGE_MASK, SUMMARY_TILE,
                               summary_candidate_tiles, tenant_permbits,
                               tile_summary)
-from repro.kernels import bucket_pad, resolve_interpret
+from repro.kernels import bucket_pad, compiler_params, resolve_interpret
 
 ADDR_BLOCK = 1024          # addresses per grid step = (8, 128) lanes
 ENTRY_TILE = 1024          # table entries folded per inner loop step
-MAX_ENTRIES = 65536        # per-shard ceiling (64 K entries, 768 KiB VMEM)
+MAX_ENTRIES = 65536        # per-shard ceiling (64 K entries, 768 KiB SMEM)
+UNROLL = 8                 # entries folded per scalar-loop iteration
 
 # Adaptive selector decision rule: the hierarchical kernel evaluates
 # candidate tiles plus a summary pass + per-tile dispatch overhead, so it
@@ -160,187 +161,100 @@ def grant_sizes(starts, ends, permbits, needv):
     With these, the range test collapses to one unsigned compare per
     entry — ``(page - start) as u32 < size`` — because a page below the
     start wraps to a huge unsigned value and a denied entry has a zero
-    window.  O(N) work, done once per wrapper trace, off the B x N path."""
-    sizes = (jnp.asarray(ends, jnp.int32)
-             - jnp.asarray(starts, jnp.int32)).astype(jnp.uint32)
+    window.  O(N) work, done once per wrapper trace, off the B x N path.
+    Returned as i32 (SMEM holds 32-bit scalars; a span of 24-bit pages
+    fits), compared unsigned in the kernel."""
+    sizes = jnp.asarray(ends, jnp.int32) - jnp.asarray(starts, jnp.int32)
     permbits = jnp.asarray(permbits, jnp.uint32)
-    sizes_ok = jnp.where((permbits & needv) == needv, sizes, jnp.uint32(0))
+    sizes_ok = jnp.where((permbits & needv) == needv, sizes, jnp.int32(0))
     return sizes, sizes_ok
 
 
-def _match_tile(page, starts, sizes, sizes_ok, t, carry):
-    """Evaluate one ENTRY_TILE slab of the table against an (R, 128) page
-    block; shared by the flat, hierarchical, and fabric-batched kernels.
-    Operands are the diff-form arrays from `grant_sizes` (callers read
-    their refs once)."""
-    any_hit, idx = carry
-    s = jax.lax.dynamic_slice(starts, (t * ENTRY_TILE,), (ENTRY_TILE,))
-    sz = jax.lax.dynamic_slice(sizes, (t * ENTRY_TILE,), (ENTRY_TILE,))
-    szok = jax.lax.dynamic_slice(sizes_ok, (t * ENTRY_TILE,), (ENTRY_TILE,))
-    # (R, 128, ENTRY_TILE) predicate evaluated on the VPU: one subtract
-    # plus unsigned compares (wraparound stands in for the >= start test)
-    diff = (page[..., None] - s).astype(jnp.uint32)
-    in_r = diff < sz
-    any_hit = any_hit | jnp.any(diff < szok, axis=-1)
-    local = jnp.argmax(in_r, axis=-1).astype(jnp.int32) + t * ENTRY_TILE
-    idx = jnp.where(jnp.any(in_r, axis=-1) & (idx < 0), local, idx)
-    return any_hit, idx
-
-
-def _flat_search(page, starts, sizes, sizes_ok, n_tiles: int):
-    """Brute-force scan of every tile over an (R, 128) page block.
-    Returns (any_hit bool(R,128), idx i32(R,128))."""
-    def tile_step(t, carry):
-        return _match_tile(page, starts, sizes, sizes_ok, t, carry)
-
-    init = (jnp.zeros(page.shape, bool), jnp.full(page.shape, -1, jnp.int32))
-    return jax.lax.fori_loop(0, n_tiles, tile_step, init)
-
-
-def _permcheck_flat_kernel(addr_ref, starts_ref, sizes_ref, sizes_ok_ref,
-                           allowed_ref, idx_ref, *, hwpid: int,
-                           n_entries: int):
-    ext = addr_ref[...].astype(jnp.int32).reshape(8, 128)
-    tag = ext >> HWPID_SHIFT
-    page = ext & PAGE_MASK
-    tag_ok = tag == jnp.int32(hwpid)
-
-    any_hit, idx = _flat_search(page, starts_ref[...], sizes_ref[...],
-                                sizes_ok_ref[...], n_entries // ENTRY_TILE)
-
-    allowed_ref[...] = (tag_ok & any_hit).astype(jnp.uint32).reshape(
-        allowed_ref.shape)
-    idx_ref[...] = idx.reshape(idx_ref.shape)
-
-
-def _hier_search(page, starts, sizes, sizes_ok, tmin, tmax, n_tiles: int):
-    """Two-level search over an (R, 128) page block; shared by the
-    hierarchical permcheck kernel, the fused egress kernel, and the
-    fabric-batched multi-host kernel (operands are plain arrays — callers
-    read and reshape their refs once).
-
-    Level 1: cheap (R, 128, n_tiles) overlap test against the summary.
-    Sorted non-overlapping entries make the tile windows non-overlapping,
-    so each lane has at most one candidate; evaluating a superset of tiles
-    is only ever extra work, never a wrong answer.
-
-    Level 2: full (R, 128, ENTRY_TILE) evaluation only over the block's
-    candidate span [t_lo, t_hi] (dynamic fori bounds: tiles outside the
-    span cost nothing at all), with sparse middles cond-skipped.
-
-    Returns (any_hit bool(R,128), idx i32(R,128)).
-    """
-    cand = (page[..., None] >= tmin) & (page[..., None] < tmax)
-    tile_needed = jnp.any(cand, axis=(0, 1))        # bool[n_tiles]
-
-    tile_ids = jax.lax.broadcasted_iota(jnp.int32, (1, n_tiles), 1)[0]
-    t_lo = jnp.min(jnp.where(tile_needed, tile_ids, n_tiles))
-    t_hi = jnp.max(jnp.where(tile_needed, tile_ids, -1))
-
-    def tile_step(t, carry):
-        def heavy(c):
-            return _match_tile(page, starts, sizes, sizes_ok, t, c)
-        return jax.lax.cond(tile_needed[t], heavy, lambda c: c, carry)
-
-    init = (jnp.zeros(page.shape, bool), jnp.full(page.shape, -1, jnp.int32))
-    return jax.lax.fori_loop(t_lo, t_hi + 1, tile_step, init)
-
-
 # ---------------------------------------------------------------------------
-# Cover-only searches (fused egress kernels)
+# In-kernel search (shared by permcheck, the fused egress kernel and the
+# fabric-batched kernel)
 # ---------------------------------------------------------------------------
-# The fused check⊕decrypt kernels need only two bits per lane — "some entry
-# grants `need`" and "some entry covers the page" (for the NO_ENTRY vs PERM
-# fault split) — never the matched entry *index*.  Dropping the argmax/index
-# bookkeeping of `_match_tile` removes two full (R, 128, ENTRY_TILE)
-# reduction passes per tile, a measured double-digit slice of the fused
-# kernel's inner loop.
+# The entry arrays and the tile summary sit in SMEM as (1, N) rows and are
+# read one scalar per entry: each entry costs a few VPU ops on the (R, 128)
+# page block, and no intermediate grows with the entry count.  (A vectorized
+# (R, 128, ENTRY_TILE) predicate needs 64·128·1024·4 B = 32 MiB of VMEM for a
+# 64-row block, and its lane reductions do not lower.)  Loop carries are
+# i32, not bool: Mosaic cannot carry i1 vectors through a loop.
 
-def _cover_tile(page, starts, sizes, sizes_ok, t, carry):
-    any_ok, covered = carry
-    s = jax.lax.dynamic_slice(starts, (t * ENTRY_TILE,), (ENTRY_TILE,))
-    sz = jax.lax.dynamic_slice(sizes, (t * ENTRY_TILE,), (ENTRY_TILE,))
-    szok = jax.lax.dynamic_slice(sizes_ok, (t * ENTRY_TILE,), (ENTRY_TILE,))
-    diff = (page[..., None] - s).astype(jnp.uint32)
-    return (any_ok | jnp.any(diff < szok, axis=-1),
-            covered | jnp.any(diff < sz, axis=-1))
+def _fold_entries(page, ent, k0, n: int, carry):
+    """Fold table entries [k0, k0 + n) into the per-lane search state.
+    ``ent`` = (starts, sizes, sizes_ok) SMEM refs (see `grant_sizes`);
+    ``carry`` = (ok, idx) i32: ok is 1 where some entry grants, idx the
+    first entry covering the page (-1 if none).  ``n`` is a multiple of
+    UNROLL."""
+    s_ref, sz_ref, szok_ref = ent
 
+    def one(k, c):
+        ok, idx = c
+        diff = (page - s_ref[0, k]).astype(jnp.uint32)
+        in_r = diff < sz_ref[0, k].astype(jnp.uint32)
+        ok = ok | (diff < szok_ref[0, k].astype(jnp.uint32)).astype(jnp.int32)
+        return ok, jnp.where(in_r & (idx < 0), k, idx)
 
-def _cover_search(page, starts, sizes, sizes_ok, tmin, tmax, n_tiles: int,
-                  use_hier):
-    """Adaptive cover-only search over an (R, 128) page block: `use_hier`
-    (a traced scalar, typically a selector operand) picks the two-level
-    candidate-span walk or the brute-force scan per kernel step.  Returns
-    (any_ok bool(R,128), covered bool(R,128))."""
-    init = (jnp.zeros(page.shape, bool), jnp.zeros(page.shape, bool))
+    def body(u, c):
+        for r in range(UNROLL):
+            c = one(k0 + u * UNROLL + r, c)
+        return c
 
-    def flat(_):
-        def tile_step(t, carry):
-            return _cover_tile(page, starts, sizes, sizes_ok, t, carry)
-        return jax.lax.fori_loop(0, n_tiles, tile_step, init)
-
-    def hier(_):
-        cand = (page[..., None] >= tmin) & (page[..., None] < tmax)
-        tile_needed = jnp.any(cand, axis=(0, 1))
-        tile_ids = jax.lax.broadcasted_iota(jnp.int32, (1, n_tiles), 1)[0]
-        t_lo = jnp.min(jnp.where(tile_needed, tile_ids, n_tiles))
-        t_hi = jnp.max(jnp.where(tile_needed, tile_ids, -1))
-
-        def tile_step(t, carry):
-            def heavy(c):
-                return _cover_tile(page, starts, sizes, sizes_ok, t, c)
-            return jax.lax.cond(tile_needed[t], heavy, lambda c: c, carry)
-
-        return jax.lax.fori_loop(t_lo, t_hi + 1, tile_step, init)
-
-    if n_tiles <= 1:        # summary can't skip anything: no branch at all
-        return flat(None)
-    return jax.lax.cond(use_hier, hier, flat, None)
+    return jax.lax.fori_loop(0, n // UNROLL, body, carry)
 
 
-def _permcheck_hier_kernel(addr_ref, starts_ref, sizes_ref, sizes_ok_ref,
-                           tmin_ref, tmax_ref, allowed_ref, idx_ref, *,
-                           hwpid: int, n_entries: int):
-    ext = addr_ref[...].astype(jnp.int32).reshape(8, 128)
+def search(page, ent, summary, n_tiles: int, use_hier):
+    """Range/permission lookup of an (R, 128) page block.
+
+    flat: fold every entry.  hier: test each tile's [min start, max end)
+    summary window against the block and fold only the tiles some lane
+    falls in (sorted, non-overlapping entries make the windows disjoint,
+    so a superset of tiles is extra work, never a wrong answer).
+    ``use_hier`` is a Python bool (fixed mode) or a traced bool scalar (the
+    adaptive selector).  Returns (any_ok bool(R,128), idx i32(R,128))."""
+    init = (jnp.zeros(page.shape, jnp.int32),
+            jnp.full(page.shape, -1, jnp.int32))
+
+    def flat(c):
+        return _fold_entries(page, ent, 0, n_tiles * ENTRY_TILE, c)
+
+    def hier(c):
+        tmin_ref, tmax_ref = summary
+
+        def tile(t, c):
+            cand = (page >= tmin_ref[0, t]) & (page < tmax_ref[0, t])
+            return jax.lax.cond(
+                jnp.max(cand.astype(jnp.int32)) > 0,
+                lambda c: _fold_entries(page, ent, t * ENTRY_TILE,
+                                        ENTRY_TILE, c),
+                lambda c: c, c)
+
+        return jax.lax.fori_loop(0, n_tiles, tile, c)
+
+    if n_tiles <= 1 or use_hier is False:  # one tile: nothing to skip
+        ok, idx = flat(init)
+    elif use_hier is True:
+        ok, idx = hier(init)
+    else:
+        ok, idx = jax.lax.cond(use_hier, hier, flat, init)
+    return ok > 0, idx
+
+
+def _permcheck_kernel(addr_ref, sel_ref, starts_ref, sizes_ref, sizes_ok_ref,
+                      tmin_ref, tmax_ref, allowed_ref, idx_ref, *,
+                      hwpid: int, n_tiles: int, mode: str):
+    """One ADDR_BLOCK of addresses; ``mode="adaptive"`` reads the selector
+    `sel_ref[0, 0]` (computed by the wrapper from the tile summary), so one
+    compiled kernel covers every trace class."""
+    ext = addr_ref[...].reshape(8, 128)
     tag = ext >> HWPID_SHIFT
     page = ext & PAGE_MASK
-    tag_ok = tag == jnp.int32(hwpid)
-
-    any_hit, idx = _hier_search(page, starts_ref[...], sizes_ref[...],
-                                sizes_ok_ref[...], tmin_ref[...],
-                                tmax_ref[...], n_entries // ENTRY_TILE)
-
-    allowed_ref[...] = (tag_ok & any_hit).astype(jnp.uint32).reshape(
-        allowed_ref.shape)
-    idx_ref[...] = idx.reshape(idx_ref.shape)
-
-
-def _permcheck_adaptive_kernel(addr_ref, sel_ref, starts_ref, sizes_ref,
-                               sizes_ok_ref, tmin_ref, tmax_ref, allowed_ref,
-                               idx_ref, *, hwpid: int, n_entries: int):
-    """Selector-driven kernel: `sel_ref[0]` (computed by the wrapper from
-    the tile summary) picks the hierarchical or flat search per grid step
-    via `lax.cond` — one compiled kernel covers every trace class."""
-    ext = addr_ref[...].astype(jnp.int32).reshape(8, 128)
-    tag = ext >> HWPID_SHIFT
-    page = ext & PAGE_MASK
-    tag_ok = tag == jnp.int32(hwpid)
-
-    n_tiles = n_entries // ENTRY_TILE
-    starts, sizes = starts_ref[...], sizes_ref[...]
-    sizes_ok = sizes_ok_ref[...]
-
-    def hier(_):
-        return _hier_search(page, starts, sizes, sizes_ok, tmin_ref[...],
-                            tmax_ref[...], n_tiles)
-
-    def flat(_):
-        return _flat_search(page, starts, sizes, sizes_ok, n_tiles)
-
-    any_hit, idx = jax.lax.cond(sel_ref[0] > 0, hier, flat, None)
-
-    allowed_ref[...] = (tag_ok & any_hit).astype(jnp.uint32).reshape(
-        allowed_ref.shape)
+    use_hier = sel_ref[0, 0] > 0 if mode == "adaptive" else mode == "hier"
+    any_hit, idx = search(page, (starts_ref, sizes_ref, sizes_ok_ref),
+                          (tmin_ref, tmax_ref), n_tiles, use_hier)
+    allowed = (tag == jnp.int32(hwpid)) & any_hit
+    allowed_ref[...] = allowed.astype(jnp.uint32).reshape(allowed_ref.shape)
     idx_ref[...] = idx.reshape(idx_ref.shape)
 
 
@@ -372,6 +286,12 @@ def selected_mode(ext_addrs, view: ShardView, *,
     ext = jnp.full((bp,), -1, jnp.int32).at[:b.shape[0]].set(b)
     return "hier" if bool(hier_profitable(
         ext, view.tile_min, view.tile_max, block=block)) else "flat"
+
+
+def smem_rows(*arrays):
+    """Entry and summary arrays as the (1, N) i32 rows the kernels read
+    from SMEM."""
+    return tuple(jnp.asarray(a, jnp.int32).reshape(1, -1) for a in arrays)
 
 
 def _pad_shard(starts, ends, permbits):
@@ -416,64 +336,29 @@ def permcheck_view_pallas(ext_addrs, view: ShardView, *, hwpid: int,
     bp = bucket_pad(b, ADDR_BLOCK)
     ext = jnp.full((bp,), -1, jnp.int32).at[:b].set(
         jnp.asarray(ext_addrs, jnp.int32))
-    s = view.starts
-    sz, szok = grant_sizes(s, view.ends, view.permbits, jnp.uint32(need))
-    np_ = s.shape[0]
-    n_tiles = view.n_tiles
-    if mode == "adaptive" and n_tiles <= 1:
-        mode = "flat"       # single tile: the summary can't skip anything
-
-    grid = (bp // ADDR_BLOCK,)
-    entry_specs = [
-        pl.BlockSpec((np_,), lambda i: (0,)),
-        pl.BlockSpec((np_,), lambda i: (0,)),
-        pl.BlockSpec((np_,), lambda i: (0,)),
-    ]
-    summary_specs = [
-        pl.BlockSpec((n_tiles,), lambda i: (0,)),
-        pl.BlockSpec((n_tiles,), lambda i: (0,)),
-    ]
-    out_specs = [
-        pl.BlockSpec((ADDR_BLOCK,), lambda i: (i,)),
-        pl.BlockSpec((ADDR_BLOCK,), lambda i: (i,)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((bp,), jnp.uint32),
-        jax.ShapeDtypeStruct((bp,), jnp.int32),
-    ]
-    if mode == "flat":
-        kernel = functools.partial(_permcheck_flat_kernel, hwpid=hwpid,
-                                   n_entries=np_)
-        operands = (ext, s, sz, szok)
-        in_specs = [pl.BlockSpec((ADDR_BLOCK,), lambda i: (i,))] + entry_specs
-    elif mode == "hier":
-        kernel = functools.partial(_permcheck_hier_kernel, hwpid=hwpid,
-                                   n_entries=np_)
-        operands = (ext, s, sz, szok, view.tile_min, view.tile_max)
-        in_specs = ([pl.BlockSpec((ADDR_BLOCK,), lambda i: (i,))] +
-                    entry_specs + summary_specs)
-    else:
-        sel = hier_profitable(ext, view.tile_min, view.tile_max)
-        kernel = functools.partial(_permcheck_adaptive_kernel, hwpid=hwpid,
-                                   n_entries=np_)
-        operands = (ext, sel.astype(jnp.int32).reshape(1), s, sz, szok,
-                    view.tile_min, view.tile_max)
-        in_specs = ([pl.BlockSpec((ADDR_BLOCK,), lambda i: (i,)),
-                     pl.BlockSpec((1,), lambda i: (0,))] +
-                    entry_specs + summary_specs)
-
+    sz, szok = grant_sizes(view.starts, view.ends, view.permbits,
+                           jnp.uint32(need))
+    sel = (hier_profitable(ext, view.tile_min, view.tile_max)
+           if mode == "adaptive" else jnp.asarray(False))
+    block = pl.BlockSpec((ADDR_BLOCK,), lambda i: (i,))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    kernel = functools.partial(_permcheck_kernel, hwpid=hwpid,
+                               n_tiles=view.n_tiles, mode=mode)
     allowed, idx = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
+        grid=(bp // ADDR_BLOCK,),
+        in_specs=[block] + [smem] * 6,
+        out_specs=[block, block],
+        out_shape=[
+            jax.ShapeDtypeStruct((bp,), jnp.uint32),
+            jax.ShapeDtypeStruct((bp,), jnp.int32),
+        ],
         interpret=interpret,
         # each ADDR_BLOCK of addresses is checked independently against the
         # (replicated) entry arrays — the grid is embarrassingly parallel
-        **({} if interpret else {"compiler_params": pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel",))}),
-    )(*operands)
+        **compiler_params(interpret, "parallel"),
+    )(ext, sel.astype(jnp.int32).reshape(1, 1),
+      *smem_rows(view.starts, sz, szok, view.tile_min, view.tile_max))
     return allowed[:b].astype(bool), idx[:b]
 
 

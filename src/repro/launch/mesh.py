@@ -6,6 +6,15 @@ touches jax device state (the dry-run sets XLA_FLAGS before any jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AbstractMesh, AxisType
+
+
+def _auto_mesh(shape, axes):
+    """Mesh whose axes are all Auto: the partitioner places what is not
+    pinned, and `with_sharding_constraint` (launch/activations.py) may
+    name them.  `jax.make_mesh` defaults to Explicit axes, which refuse
+    such constraints."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -13,7 +22,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2x16x16 = 512 chips over ("pod", "data", "model")."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def data_axes(mesh) -> tuple[str, ...]:
@@ -23,14 +32,10 @@ def data_axes(mesh) -> tuple[str, ...]:
 
 def make_smoke_mesh():
     """1-device mesh for CPU smoke tests (same axis names as single-pod)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_abstract_mesh(shape, axes):
-    """AbstractMesh across jax versions: 0.4.x takes a single
-    ((name, size), ...) shape tuple; >=0.5 takes (sizes, names)."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    """Device-free mesh of the given shape, for sharding rules and specs."""
+    return AbstractMesh(tuple(shape), tuple(axes),
+                        axis_types=(AxisType.Auto,) * len(axes))

@@ -52,6 +52,7 @@ from repro.core import (FAULT_DESYNC, FAULT_NONE, SharedTensorPool,
                         pack_ext_addr)
 from repro.core.fabric import ShardedFabric
 from repro.core.table import PAGE_BYTES
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry
 
 
@@ -75,6 +76,9 @@ class Tenant:
     pos: int = 0
     gen_left: int = 0
     last_fault: int = FAULT_NONE
+    # logits [B, V] behind the group's newest token (prefill, then each
+    # decode step): what a reference forward pass is compared with
+    last_logits: jax.Array | None = None
 
 
 class ServeEngine:
@@ -204,11 +208,12 @@ class ServeEngine:
             toks[i, :len(p)] = p
         logits, cache = registry.prefill(
             self.cfg, self.params, {"tokens": jnp.asarray(toks)},
-            cache_dtype=jnp.float32, cap=plen + gen)
+            cache_dtype=self.cfg.pdtype, cap=plen + gen)
         t.group = group
         t.cache = cache
         t.out = [list(p) for p in group]
         t.cur = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
+        t.last_logits = logits[:, -1]
         t.plen = plen
         t.pos = plen
         t.gen_left = gen
@@ -324,13 +329,16 @@ class ServeEngine:
                 results[t.name] = {"aborted": True, "stalled": False,
                                    "fault": fault, "retired": 0}
                 continue
+            # the token fed this tick is the one served: its KV line is
+            # what the check above released
+            for i in range(len(t.group)):
+                t.out[i].append(int(t.cur[i, 0]))
             logits, t.cache = self._decode(
                 self.params, t.cache, t.cur,
                 jnp.asarray(t.pos, jnp.int32))
             t.cur = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(
                 jnp.int32)
-            for i in range(len(t.group)):
-                t.out[i].append(int(t.cur[i, 0]))
+            t.last_logits = logits[:, -1]
             t.pos += 1
             t.gen_left -= 1
             self.steps += 1
@@ -386,6 +394,7 @@ def main() -> None:
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--batch", type=int, default=4)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = ARCHS[args.arch] if args.preset == "full" \
         else smoke_config(ARCHS[args.arch])

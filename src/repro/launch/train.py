@@ -34,6 +34,7 @@ from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.launch import sharding as sh
 from repro.launch.mesh import make_smoke_mesh
 from repro.launch.steps import build_train_step
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry
 from repro.optim import init_state
 
@@ -70,6 +71,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--log-file", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = preset_config(args.arch, args.preset)
     mesh = make_smoke_mesh()

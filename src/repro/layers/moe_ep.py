@@ -39,25 +39,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-# jax >= 0.5 exposes jax.shard_map(check_vma=...); 0.4.x has
-# jax.experimental.shard_map.shard_map(check_rep=...).  The kwarg is chosen
-# from the function's own signature, not the jax version, because
-# transitional releases ship jax.shard_map with the old check_rep name.
-import inspect
-
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-_CHECK_KW = ("check_vma" if "check_vma"
-             in inspect.signature(_shard_map).parameters else "check_rep")
+from repro.launch.activations import current_mesh
 
 
 def _shmap(body, mesh, in_specs, out_specs):
-    return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: False})
-
-from repro.launch.activations import current_mesh
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.launch.activations import BATCH, MODEL, constrain, current_mesh
+from repro.launch.mesh import make_smoke_mesh
 
 
 def test_constrain_noop_without_mesh():
@@ -18,7 +19,7 @@ def test_constrain_noop_without_mesh():
 
 
 def test_constrain_under_mesh_divisible():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_smoke_mesh()
 
     def f(x):
         return constrain(x, BATCH, MODEL) * 2
@@ -30,7 +31,7 @@ def test_constrain_under_mesh_divisible():
 
 def test_constrain_drops_nondivisible_axes():
     """A dim that doesn't divide its axes is replicated, not an error."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_smoke_mesh()
 
     def f(x):
         # 7 % anything==1 ok on 1x1, but the helper must also tolerate

@@ -232,6 +232,42 @@ def test_vmem_worst_case_fallback_and_unresolved():
     assert rows2[0]["unresolved"] == "mystery_dim"
 
 
+def test_vmem_resolves_grid_spec_and_local_specs():
+    """Scalar-prefetch grid specs, specs bound to local names or built by
+    a local helper, and the compiler-params helper all resolve; SMEM
+    blocks take no VMEM."""
+    src = """
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def k(x, h, n):
+        rows = 8
+        words = pl.BlockSpec((None, rows, 128), lambda i, j, *_: (i, j, 0))
+
+        def shard_row(m):
+            return pl.BlockSpec((None, 1, m), lambda i, j, *_: (i, 0, 0),
+                                memory_space=pltpu.SMEM)
+
+        return pl.pallas_call(
+            f,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(h, 4),
+                in_specs=[words] + [shard_row(n)] * 3,
+                out_specs=[words]),
+            out_shape=[jax.ShapeDtypeStruct((h, 32, 128), jnp.int32)],
+            **compiler_params(interpret, "parallel", "arbitrary"),
+        )(x)
+    """
+    f, rows = passes_vmem.analyze_file(
+        _parse(src), "src/x.py", REPO, budget=4 << 20)
+    assert f == []
+    (row,) = rows
+    assert row["in_bytes"] == 8 * 128 * 4 and row["out_bytes"] == 8 * 128 * 4
+    assert row["double_buffered"] is True
+
+
 def test_vmem_closure_captured_operand():
     bad = """
     import jax

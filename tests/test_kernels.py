@@ -85,6 +85,20 @@ def test_permcheck_capacity_guard(rng):
                          hwpid=1, need=1, interpret=True)
 
 
+def test_ops_pallas_path_raises_beyond_max_entries():
+    """A table the kernels cannot hold raises on the Pallas path; the
+    reference never answers in the kernel's place."""
+    z = jnp.zeros((MAX_ENTRIES + 1,), jnp.int32)
+    pb = jnp.zeros((MAX_ENTRIES + 1,), jnp.uint32)
+    ext = jnp.zeros((8,), jnp.int32)
+    with pytest.raises(ValueError):
+        ops.permission_check(ext, z, z, pb, hwpid=1, need=1, use_pallas=True)
+    with pytest.raises(ValueError):
+        ops.checked_memory_decrypt(jnp.zeros((8,), jnp.uint32), ext, z, z, pb,
+                                   hwpid=1, need=1, key0=1, key1=2,
+                                   use_pallas=True)
+
+
 def test_ops_dispatcher_consistency(rng):
     starts, ends, perms = _mk_table(rng, 64, 1 << 16)
     pages = rng.integers(0, 1 << 16, 100).astype(np.int32)

@@ -95,6 +95,11 @@ WORST_CASE_DIMS = {
     "n_k": 64,           # flash K-step count (grid extent, not a block dim)
 }
 
+# Helpers that build a pallas_call's compiler params from the grid's
+# dimension semantics, passed as string arguments
+# (``**compiler_params(interpret, "parallel")``).
+COMPILER_PARAMS_HELPERS = {"compiler_params"}
+
 # Element width assumed for BlockSpec operands whose dtype is not statically
 # visible (BlockSpec carries shape only).  Every egress kernel in this repo
 # moves u32/i32/f32 words; out_specs widths come from the paired

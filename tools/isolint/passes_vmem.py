@@ -18,6 +18,11 @@ words).  When the call is marked ``dimension_semantics`` *parallel*,
 Mosaic double-buffers the operand stream, so the gated figure is
 ``2 x (in + out) + scratch``.
 
+Specs bound to local names or built by a local helper are followed to
+their ``pl.BlockSpec`` call, ``grid_spec=`` calls (scalar prefetch) are
+read like top-level keywords, and blocks placed in SMEM count zero bytes
+(Mosaic's own verdict on SMEM comes from tests/test_chip_compile.py).
+
 A site whose ``in_specs`` variable has several branch-dependent
 assignments (the flat/hier/adaptive permcheck variants) yields one table
 row per variant, labelled by the branch's compared constant.
@@ -165,7 +170,10 @@ def _function_env(fn: ast.AST, module_env: dict[str, int]) -> dict[str, int]:
             if isinstance(node, ast.Assign) and len(node.targets) == 1 \
                     and isinstance(node.targets[0], ast.Name):
                 try:
-                    env[node.targets[0].id] = _eval_int(node.value, env)
+                    # a local derived from a dynamic dim (rows = sb // 128)
+                    # takes that dim's worst-case binding
+                    env[node.targets[0].id] = _eval_int(
+                        node.value, {**config.WORST_CASE_DIMS, **env})
                 except _Unresolved:
                     pass
     return env
@@ -195,6 +203,9 @@ def _resolve_list(node: ast.AST, fn: ast.AST) -> list[list[ast.AST]]:
         lefts = _resolve_list(node.left, fn)
         rights = _resolve_list(node.right, fn)
         return [lt + rt for lt in lefts for rt in rights]
+    if isinstance(node, ast.BinOp) and isinstance(node.op, ast.Mult) \
+            and isinstance(node.right, ast.Constant):   # [spec] * 3
+        return [v * node.right.value for v in _resolve_list(node.left, fn)]
     if isinstance(node, ast.Name):
         variants = []
         for n in scope_nodes(fn):
@@ -235,12 +246,66 @@ def _variant_labels(name_node: ast.AST, fn: ast.AST) -> list[str]:
     return labels or [""]
 
 
-def _block_bytes(spec: ast.AST, env: dict[str, int],
-                 itemsize: int) -> int:
-    """Bytes of one BlockSpec's block: prod(shape) * itemsize.  A bare
-    non-call spec (e.g. a Name we could not resolve) raises _Unresolved."""
+def _local_def(fn: ast.AST, name: str):
+    """The single local assignment value or nested ``def`` bound to
+    `name` in `fn`, else None."""
+    found = [n.value for n in scope_nodes(fn)
+             if isinstance(n, ast.Assign) and len(n.targets) == 1
+             and isinstance(n.targets[0], ast.Name)
+             and n.targets[0].id == name]
+    found += [n for n in ast.walk(fn)
+              if isinstance(n, ast.FunctionDef) and n.name == name
+              and n is not fn]
+    return found[0] if len(found) == 1 else None
+
+
+def _resolve_spec(spec: ast.AST, fn: ast.AST, env: dict[str, int]):
+    """Follow a spec bound to a local name (``block = pl.BlockSpec(...)``)
+    or built by a local helper (``shard_row(np_)``, whose parameters bind
+    to the call's evaluated arguments) down to its BlockSpec call."""
+    for _ in range(4):
+        if isinstance(spec, ast.Name):
+            bound = _local_def(fn, spec.id)
+            if bound is None or isinstance(bound, ast.FunctionDef):
+                break
+            spec = bound
+        elif isinstance(spec, ast.Call) and isinstance(spec.func, ast.Name):
+            helper = _local_def(fn, spec.func.id)
+            if not isinstance(helper, ast.FunctionDef):
+                break
+            rets = [n.value for n in ast.walk(helper)
+                    if isinstance(n, ast.Return) and n.value is not None]
+            if len(rets) != 1:
+                break
+            env = dict(env)
+            for arg, val in zip(helper.args.args, spec.args):
+                try:
+                    env[arg.arg] = _eval_dim(val, env)
+                except _Unresolved:
+                    pass          # surfaces only if the block shape needs it
+            spec = rets[0]
+        else:
+            break
+    return spec, env
+
+
+def _in_smem(spec: ast.Call) -> bool:
+    return any(k.arg == "memory_space" and dotted_name(k.value) is not None
+               and dotted_name(k.value).endswith("SMEM")
+               for k in spec.keywords)
+
+
+def _block_bytes(spec: ast.AST, env: dict[str, int], itemsize: int,
+                 fn: ast.AST | None = None) -> int:
+    """VMEM bytes of one BlockSpec's block: prod(shape) * itemsize, 0 for a
+    block placed in SMEM.  A spec that does not resolve to a BlockSpec
+    call raises _Unresolved."""
+    if fn is not None:
+        spec, env = _resolve_spec(spec, fn, env)
     if not isinstance(spec, ast.Call):
         raise _Unresolved(ast.dump(spec)[:40])
+    if _in_smem(spec):
+        return 0
     shape = None
     if spec.args:
         shape = spec.args[0]
@@ -267,10 +332,21 @@ def _dtype_bytes(node: ast.AST) -> int:
     return config.DTYPE_BYTES.get(name or "", config.DEFAULT_ITEMSIZE)
 
 
+def _call_kwargs(call: ast.Call) -> dict[str, ast.AST]:
+    """The pallas_call's keywords, with those of a ``grid_spec=`` call
+    (``pltpu.PrefetchScalarGridSpec(grid=..., in_specs=..., ...)``)
+    lifted to the top level."""
+    kw = {k.arg: k.value for k in call.keywords if k.arg}
+    gs = kw.get("grid_spec")
+    if isinstance(gs, ast.Call):
+        kw.update({k.arg: k.value for k in gs.keywords if k.arg})
+    return kw
+
+
 def _out_entries(call: ast.Call, fn: ast.AST):
     """Pair out_specs with out_shape dtypes, returning
     ``[(spec_node, itemsize), ...]`` (dtype defaulting when unpaired)."""
-    kw = {k.arg: k.value for k in call.keywords if k.arg}
+    kw = _call_kwargs(call)
     specs_node = kw.get("out_specs")
     shapes_node = kw.get("out_shape")
     specs = _resolve_list(specs_node, fn)[0] if specs_node is not None else []
@@ -293,8 +369,7 @@ def _out_entries(call: ast.Call, fn: ast.AST):
 
 def _scratch_bytes(call: ast.Call, env: dict[str, int]) -> int:
     """Total bytes of ``scratch_shapes`` VMEM allocations."""
-    kw = {k.arg: k.value for k in call.keywords if k.arg}
-    node = kw.get("scratch_shapes")
+    node = _call_kwargs(call).get("scratch_shapes")
     if node is None:
         return 0
     if not isinstance(node, (ast.List, ast.Tuple)):
@@ -315,9 +390,16 @@ def _scratch_bytes(call: ast.Call, env: dict[str, int]) -> int:
 
 
 def _has_dimension_semantics(call: ast.Call) -> tuple[bool, bool]:
-    """(mentions dimension_semantics, any dim marked "parallel")."""
+    """(mentions dimension_semantics, any dim marked "parallel").  A call
+    to one of ``config.COMPILER_PARAMS_HELPERS`` passes its string
+    arguments as the semantics."""
     mentions = parallel = False
     for node in ast.walk(call):
+        if isinstance(node, ast.Call) and \
+                call_name(node) in config.COMPILER_PARAMS_HELPERS:
+            mentions = True
+            parallel |= any(isinstance(a, ast.Constant) and
+                            a.value == "parallel" for a in node.args)
         if isinstance(node, ast.keyword) and \
                 node.arg == "dimension_semantics":
             mentions = True
@@ -375,7 +457,7 @@ def analyze_file(tree: ast.Module, path: str, root: pathlib.Path,
                      if isinstance(n, ast.Call)
                      and call_name(n) == "pallas_call"]:
             env = _function_env(scope, module_env)
-            kw = {k.arg: k.value for k in call.keywords if k.arg}
+            kw = _call_kwargs(call)
             literal_interp = _interpret_literal_true(call)
             if literal_interp:
                 findings.append(Finding(
@@ -402,9 +484,9 @@ def analyze_file(tree: ast.Module, path: str, root: pathlib.Path,
                 row = {"path": path, "line": call.lineno, "kernel": qual,
                        "variant": label, "budget_bytes": budget}
                 try:
-                    in_b = sum(_block_bytes(s, env, config.DEFAULT_ITEMSIZE)
-                               for s in specs)
-                    out_b = sum(_block_bytes(s, env, isz)
+                    in_b = sum(_block_bytes(s, env, config.DEFAULT_ITEMSIZE,
+                                            scope) for s in specs)
+                    out_b = sum(_block_bytes(s, env, isz, scope)
                                 for s, isz in out_entries)
                     scr_b = _scratch_bytes(call, env)
                 except _Unresolved as e:
