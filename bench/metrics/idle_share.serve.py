@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device, in
+the serving cells: 1 - busy / window, from the device trace."""
+
+
+def read(ctx):
+    t = ctx.trace
+    if t is None or t.window_s <= 0 or t.n_ops == 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
