@@ -54,6 +54,8 @@ from __future__ import annotations
 from collections import deque
 from typing import Callable, TYPE_CHECKING
 
+from jax.profiler import TraceAnnotation
+
 if TYPE_CHECKING:  # pragma: no cover - import cycle guard (fm imports bus)
     from repro.memsim.clock import ClockedFabric
     from .faults import FaultPlan
@@ -208,16 +210,19 @@ class BISnpBus:
         have arrived at `host_id`."""
         q = self._queues[host_id]
         n = len(q) if max_events is None else min(max_events, len(q))
-        if self.clock is not None:
-            target = len(q) - n
-            while len(q) > target:
-                if not self.clock.clock.step():
-                    raise RuntimeError(
-                        f"clocked bus: {len(q) - target} queued events at "
-                        f"host {host_id} have no scheduled arrival")
-            return n
-        for _ in range(n):
-            self._deliver_one(host_id, q)
+        if n == 0:
+            return 0
+        with TraceAnnotation("bus.deliver", host=host_id):
+            if self.clock is not None:
+                target = len(q) - n
+                while len(q) > target:
+                    if not self.clock.clock.step():
+                        raise RuntimeError(
+                            f"clocked bus: {len(q) - target} queued events "
+                            f"at host {host_id} have no scheduled arrival")
+                return n
+            for _ in range(n):
+                self._deliver_one(host_id, q)
         return n
 
     def deliver_until(self, host_id: int, epoch: int) -> int:
@@ -230,17 +235,20 @@ class BISnpBus:
         the number delivered.  Clocked mode runs the clock until the
         host's observed epoch reaches the fence."""
         q = self._queues[host_id]
+        if not q or q[0].epoch > epoch:
+            return 0
         n = 0
-        if self.clock is not None:
-            before = len(q)
+        with TraceAnnotation("bus.deliver", host=host_id):
+            if self.clock is not None:
+                before = len(q)
+                while q and q[0].epoch <= epoch:
+                    if not self.clock.clock.step():
+                        raise RuntimeError("clocked bus: queued event has "
+                                           "no scheduled arrival")
+                return before - len(q)
             while q and q[0].epoch <= epoch:
-                if not self.clock.clock.step():
-                    raise RuntimeError("clocked bus: queued event has no "
-                                       "scheduled arrival")
-            return before - len(q)
-        while q and q[0].epoch <= epoch:
-            self._deliver_one(host_id, q)
-            n += 1
+                self._deliver_one(host_id, q)
+                n += 1
         return n
 
     def drain(self, host_id: int | None = None) -> int:

@@ -351,26 +351,27 @@ def cached_check_access(
     # freshness, so the probe is just a tag compare; outside it every hit is
     # revalidated against the live table (a stale mapping then fails
     # validation and degrades to a miss, never to a wrong verdict).
-    set_idx = page & (n_sets - 1)
-    ctags = cache.tag[set_idx]                    # (B, ways)
-    cents = cache.entry[set_idx]                  # (B, ways)
-    way_match = (ctags == page[..., None]) & (cents >= 0)
-    probe_ok = jnp.any(way_match, axis=-1)
-    hit_way = jnp.argmax(way_match, axis=-1).astype(jnp.int32)
-    cent = jnp.take_along_axis(cents, hit_way[..., None], axis=-1)[..., 0]
-    safe_cent = jnp.clip(cent, 0, table.capacity - 1)
-    fenced = cache.epoch == jnp.asarray(table.epoch, jnp.int32)
+    with jax.named_scope("permcache_probe"):
+        set_idx = page & (n_sets - 1)
+        ctags = cache.tag[set_idx]                    # (B, ways)
+        cents = cache.entry[set_idx]                  # (B, ways)
+        way_match = (ctags == page[..., None]) & (cents >= 0)
+        probe_ok = jnp.any(way_match, axis=-1)
+        hit_way = jnp.argmax(way_match, axis=-1).astype(jnp.int32)
+        cent = jnp.take_along_axis(cents, hit_way[..., None], axis=-1)[..., 0]
+        safe_cent = jnp.clip(cent, 0, table.capacity - 1)
+        fenced = cache.epoch == jnp.asarray(table.epoch, jnp.int32)
 
-    def probe_fenced(_):
-        return probe_ok
+        def probe_fenced(_):
+            return probe_ok
 
-    def probe_revalidate(_):
-        cs = table.starts[safe_cent]
-        csz = table.sizes[safe_cent]
-        return (probe_ok & (page >= cs) & (page < cs + csz)
-                & (cs != EMPTY_START))
+        def probe_revalidate(_):
+            cs = table.starts[safe_cent]
+            csz = table.sizes[safe_cent]
+            return (probe_ok & (page >= cs) & (page < cs + csz)
+                    & (cs != EMPTY_START))
 
-    hit = jax.lax.cond(fenced, probe_fenced, probe_revalidate, None)
+        hit = jax.lax.cond(fenced, probe_fenced, probe_revalidate, None)
 
     # fast path: when the whole batch hits, skip the binary search entirely
     def slow(_):

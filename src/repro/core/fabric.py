@@ -59,6 +59,7 @@ from typing import NamedTuple, TYPE_CHECKING
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .checker import (PERM_CACHE_BYTES, cached_check_access_jit,
                       desync_check_result, invalidate_perm_cache,
@@ -141,31 +142,32 @@ class HostRuntime:
         host — `check()` then fails closed; a late copy that fills the
         last hole heals the desync on the spot (pure reordering loses
         nothing); a `snapshot=True` event rebuilds the whole view."""
-        self.bisnp_seen += 1
-        if self.fabric.host_monitor is not None:
-            self.fabric.host_monitor.beat(self.host_id)
-        if ev.snapshot:
-            self._apply_snapshot(ev)
-            return
-        if ev.seq >= 0:
-            if ev.seq == self._expected_seq:
-                self._expected_seq += 1
-            elif ev.seq > self._expected_seq:
-                self._missing.update(range(self._expected_seq, ev.seq))
-                self._expected_seq = ev.seq + 1
-                self.desync_events += 1
-            else:
-                # replay/duplicate/late copy: if it fills a recorded hole
-                # the "loss" was reordering — every effect has now been
-                # applied, so the fail-closed window can end immediately
-                if ev.seq in self._missing:
-                    self._missing.discard(ev.seq)
-                    if not self._missing and not self.quarantined:
-                        self.self_heals += 1
-                        self._reset_backoff()
-        self.permcache = invalidate_perm_cache(
-            self.permcache, ev.start_page, ev.n_pages, ev.epoch,
-            min_shifted_entry=ev.min_entry_idx)
+        with TraceAnnotation("host.on_bisnp", host=self.host_id):
+            self.bisnp_seen += 1
+            if self.fabric.host_monitor is not None:
+                self.fabric.host_monitor.beat(self.host_id)
+            if ev.snapshot:
+                self._apply_snapshot(ev)
+                return
+            if ev.seq >= 0:
+                if ev.seq == self._expected_seq:
+                    self._expected_seq += 1
+                elif ev.seq > self._expected_seq:
+                    self._missing.update(range(self._expected_seq, ev.seq))
+                    self._expected_seq = ev.seq + 1
+                    self.desync_events += 1
+                else:
+                    # replay/duplicate/late copy: if it fills a recorded hole
+                    # the "loss" was reordering — every effect has now been
+                    # applied, so the fail-closed window can end immediately
+                    if ev.seq in self._missing:
+                        self._missing.discard(ev.seq)
+                        if not self._missing and not self.quarantined:
+                            self.self_heals += 1
+                            self._reset_backoff()
+            self.permcache = invalidate_perm_cache(
+                self.permcache, ev.start_page, ev.n_pages, ev.epoch,
+                min_shifted_entry=ev.min_entry_idx)
 
     # -- loss recovery (fail closed, then resync) ----------------------------
     @property
@@ -262,38 +264,39 @@ class HostRuntime:
         ht = self.fabric.fm.table
         if self._shard is not None and self._shard_epoch == ht.epoch:
             return self._shard
-        n = ht.n
-        starts = ht.starts[:n]
-        ends = starts + ht.sizes[:n]
-        keep = np.zeros(n, bool)
-        for lo, hi in self.resident_ranges():
-            i0 = int(np.searchsorted(ends, lo, side="right"))
-            i1 = int(np.searchsorted(starts, hi, side="left"))
-            keep[i0:i1] = True
-        idx = np.flatnonzero(keep)
-        if self._shard_idx is not None and \
-                not np.array_equal(idx, self._shard_idx):
-            # Shard MEMBERSHIP changed — possible even when the commit was
-            # globally index-stable (a count-preserving geometry change,
-            # e.g. revoke_range split+coalesce, can grow an entry into the
-            # resident range).  Every later entry's shard-local rank then
-            # shifts, and the PermCache's cached (page -> rank) mappings
-            # for untouched pages would dangle: inside the fence a stale
-            # rank is trusted without revalidation and a valid grant would
-            # be denied.  Flush index mappings locally (targeted-drop form;
-            # the epoch fence itself is untouched — only bus events move
-            # it).  Extraction always precedes the probe in `check`, so the
-            # flush lands before any fenced hit at the new epoch.
-            self.permcache = invalidate_perm_cache(
-                self.permcache, 0, 0, int(self.permcache.epoch),
-                min_shifted_entry=0)
-        self._shard_idx = idx
-        self._shard = (starts[idx].copy(), ends[idx].copy(),
-                       ht.perms[:n][idx].copy())
-        self._shard_epoch = ht.epoch
-        self._shard_table = None
-        self.shard_rebuilds += 1
-        return self._shard
+        with TraceAnnotation("host.shard_extract", host=self.host_id):
+            n = ht.n
+            starts = ht.starts[:n]
+            ends = starts + ht.sizes[:n]
+            keep = np.zeros(n, bool)
+            for lo, hi in self.resident_ranges():
+                i0 = int(np.searchsorted(ends, lo, side="right"))
+                i1 = int(np.searchsorted(starts, hi, side="left"))
+                keep[i0:i1] = True
+            idx = np.flatnonzero(keep)
+            if self._shard_idx is not None and \
+                    not np.array_equal(idx, self._shard_idx):
+                # Shard MEMBERSHIP changed — possible even when the commit was
+                # globally index-stable (a count-preserving geometry change,
+                # e.g. revoke_range split+coalesce, can grow an entry into the
+                # resident range).  Every later entry's shard-local rank then
+                # shifts, and the PermCache's cached (page -> rank) mappings
+                # for untouched pages would dangle: inside the fence a stale
+                # rank is trusted without revalidation and a valid grant would
+                # be denied.  Flush index mappings locally (targeted-drop form;
+                # the epoch fence itself is untouched — only bus events move
+                # it).  Extraction always precedes the probe in `check`, so the
+                # flush lands before any fenced hit at the new epoch.
+                self.permcache = invalidate_perm_cache(
+                    self.permcache, 0, 0, int(self.permcache.epoch),
+                    min_shifted_entry=0)
+            self._shard_idx = idx
+            self._shard = (starts[idx].copy(), ends[idx].copy(),
+                           ht.perms[:n][idx].copy())
+            self._shard_epoch = ht.epoch
+            self._shard_table = None
+            self.shard_rebuilds += 1
+            return self._shard
 
     def shard_entries(self) -> int:
         """Committed entries in this host's resident shard (forces an
@@ -723,13 +726,14 @@ class ShardedFabric:
         if self._fabric_view is not None and self._fabric_view_key == key:
             self.view_reuses += 1
             return self._fabric_view
-        views = [self.runtimes[h].shard_view(p) for h, p in rows]
-        self._fabric_view = stack_views(
-            views, [p for _, p in rows], [h for h, _ in rows],
-            epoch=self.fm.table.epoch)
-        self._fabric_view_key = key
-        self.view_rebuilds += 1
-        return self._fabric_view
+        with TraceAnnotation("fabric.view", rows=len(rows)):
+            views = [self.runtimes[h].shard_view(p) for h, p in rows]
+            self._fabric_view = stack_views(
+                views, [p for _, p in rows], [h for h, _ in rows],
+                epoch=self.fm.table.epoch)
+            self._fabric_view_key = key
+            self.view_rebuilds += 1
+            return self._fabric_view
 
     def step_egress(self, data, ext_addrs, hwpid_by_host: dict,
                     *, need: int = 1, key0: int = 0xAB, key1: int = 0xCD):

@@ -32,6 +32,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Iterator
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .bus import BISnpBus
 from .crypto import derive_key, hmac_label
@@ -199,25 +200,27 @@ class FabricManager:
             self._txn_effects.clear()
 
     def _commit_and_broadcast(self) -> CommitInfo | None:
-        info = self.table.commit()
-        if info is not None:
-            ranges = info.ranges or ((info.start_page, info.n_pages),)
-            # write-ahead: the journal learns about this commit before any
-            # host does, so a crash mid-broadcast cannot lose it
-            rec = JournalRecord(epoch=info.epoch, ranges=tuple(ranges),
-                                min_entry_idx=info.min_shifted_entry,
-                                hwpid_ops=tuple(self._pending_hwpid_ops))
-            self._pending_hwpid_ops.clear()
-            self.journal.append(rec)
-            if self.faults is not None and \
-                    self.faults.should_crash_fm(info.epoch):
-                self.crash()   # journaled but never broadcast — the
-                return info    # restart path owes the fabric this record
-            for start, n in ranges:
-                self._broadcast(BISnpEvent(start, n, epoch=info.epoch,
-                                           min_entry_idx=info.min_shifted_entry))
-            rec.broadcast = True
-        return info
+        with TraceAnnotation("fm.commit", epoch=self.table.epoch + 1):
+            info = self.table.commit()
+            if info is not None:
+                ranges = info.ranges or ((info.start_page, info.n_pages),)
+                # write-ahead: the journal learns about this commit before any
+                # host does, so a crash mid-broadcast cannot lose it
+                rec = JournalRecord(epoch=info.epoch, ranges=tuple(ranges),
+                                    min_entry_idx=info.min_shifted_entry,
+                                    hwpid_ops=tuple(self._pending_hwpid_ops))
+                self._pending_hwpid_ops.clear()
+                self.journal.append(rec)
+                if self.faults is not None and \
+                        self.faults.should_crash_fm(info.epoch):
+                    self.crash()   # journaled but never broadcast — the
+                    return info    # restart path owes the fabric this record
+                for start, n in ranges:
+                    self._broadcast(BISnpEvent(
+                        start, n, epoch=info.epoch,
+                        min_entry_idx=info.min_shifted_entry))
+                rec.broadcast = True
+            return info
 
     def _mutate_table(self, fn):
         """Run `fn()` (table mutations) inside the open transaction, or as a

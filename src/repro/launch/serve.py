@@ -36,6 +36,13 @@ batching at tenant-group granularity): every active tenant decodes one
 token per engine step, finished request groups retire and their slots
 refill from the tenant's queue, and tenants can join or leave between any
 two steps.
+
+Observability (docs/observability.md): `step` writes one profiler span
+per phase (`serve.step`, `serve.gather`, `serve.prefill`, `serve.fence`,
+`serve.check`, `serve.egress`, then `serve.verdict`, `serve.emit` and
+`serve.decode` per tenant).  They cost about a microsecond each while no
+profiler runs; `host_reads` counts the step's blocking device-to-host
+reads.
 """
 from __future__ import annotations
 
@@ -46,6 +53,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs import ARCHS, smoke_config
 from repro.core import (FAULT_DESYNC, FAULT_NONE, SharedTensorPool,
@@ -102,10 +110,17 @@ class ServeEngine:
                                     n_shards=n_hosts)
         self.fm = self.fabric.fm
         self.tenants: dict[str, Tenant] = {}
-        self._decode = jax.jit(
-            lambda p, c, t, pos: registry.decode_step(cfg, p, c, t, pos))
+
+        def serve_decode(p, c, t, pos):
+            return registry.decode_step(cfg, p, c, t, pos)
+
+        self._decode = jax.jit(serve_decode)
         self.faults = 0
         self.steps = 0
+        # blocking device-to-host reads made by `step`: the fused-egress
+        # cross-check and the verdict of each tenant, the fault read of a
+        # deny, and each served token
+        self.host_reads = 0
         # fail-closed stalls: step ticks where a tenant's host was desynced
         # (lost BISnp events) and denied the batch WITHOUT aborting the
         # group — the tenant retries next tick and recovers after resync
@@ -254,65 +269,80 @@ class ServeEngine:
         Returns {tenant: {"aborted": bool, "fault": int, "retired": int}}
         for tenants that made progress this tick.
         """
+        with TraceAnnotation("serve.step"):
+            return self._step(gen, only)
+
+    def _step(self, gen: int, only: str | None) -> dict:
         results: dict[str, dict] = {}
         # phase 1: start groups, collect every active tenant's KV touch set
         active: list[tuple[Tenant, jax.Array]] = []
-        for name, t in list(self.tenants.items()):
-            if only is not None and name != only:
-                continue
-            if self.fabric.runtimes[t.host_id].crashed:
-                # fail-stop host: its tenants stall (queued + in-flight
-                # work held) until rejoin_host brings it back cold
-                if t.queue or t.group is not None:
-                    self.stalls += 1
-                    t.last_fault = FAULT_DESYNC
-                    results[name] = {"aborted": False, "stalled": True,
-                                     "fault": FAULT_DESYNC, "retired": 0}
-                continue
-            if t.group is None:
-                if not t.queue:
+        with TraceAnnotation("serve.gather"):
+            for name, t in list(self.tenants.items()):
+                if only is not None and name != only:
                     continue
-                self._start_group(t, gen)
-            pages = self._kv_pages_for_step(t)
-            ext = pack_ext_addr(
-                jnp.full(pages.shape, t.hwpid, jnp.int32), pages)
-            active.append((t, ext))
+                if self.fabric.runtimes[t.host_id].crashed:
+                    # fail-stop host: its tenants stall (queued + in-flight
+                    # work held) until rejoin_host brings it back cold
+                    if t.queue or t.group is not None:
+                        self.stalls += 1
+                        t.last_fault = FAULT_DESYNC
+                        results[name] = {"aborted": False, "stalled": True,
+                                         "fault": FAULT_DESYNC, "retired": 0}
+                    continue
+                if t.group is None:
+                    if not t.queue:
+                        continue
+                    with TraceAnnotation("serve.prefill", tenant=name):
+                        self._start_group(t, gen)
+                pages = self._kv_pages_for_step(t)
+                ext = pack_ext_addr(
+                    jnp.full(pages.shape, t.hwpid, jnp.int32), pages)
+                active.append((t, ext))
         if not active:
             return results
         # phase 2: close each involved host's BISnp fence up to the table
         # epoch it is about to check against (no fabric-wide quiesce).
         # Crashed hosts are detached from the bus — nothing to close there
         # (their tenants raise/stall in phase 3/4, not here).
-        for host_id in {t.host_id for t, _ in active}:
-            if host_id in self.fm.bus.hosts:
-                self.fm.bus.deliver_until(host_id, self.fm.epoch)
+        with TraceAnnotation("serve.fence"):
+            for host_id in {t.host_id for t, _ in active}:
+                if host_id in self.fm.bus.hosts:
+                    self.fm.bus.deliver_until(host_id, self.fm.epoch)
         # phase 3: framework egress check per tenant, through the host's
         # fenced PermCache and resident shard (THE checked egress path).
         # A desynced host answers a uniform FAULT_DESYNC deny here.
-        checks = [self.fabric.runtimes[t.host_id].check(
-            ext, jnp.ones(ext.shape, bool)) for t, ext in active]
-        if self.fused_egress:
+        with TraceAnnotation("serve.check"):
+            checks = [self.fabric.runtimes[t.host_id].check(
+                ext, jnp.ones(ext.shape, bool)) for t, ext in active]
             # device-level egress: one batched launch for all tenants; the
             # kernel's fault lanes must agree with the framework verdicts.
             # Desynced hosts are excluded — their deny is a control-plane
             # stall, not a permission verdict, and the kernel (which only
             # knows the table) cannot be expected to reproduce it.
             fusable = [(t, e) for t, e in active
-                       if not self.fabric.runtimes[t.host_id].desynced]
+                       if not self.fabric.runtimes[t.host_id].desynced] \
+                if self.fused_egress else []
             if fusable:
-                chk_by_name = {t.name: chk
-                               for (t, _), chk in zip(active, checks)}
-                for (t, _), kfault in zip(fusable,
-                                          self._fused_step_egress(fusable)):
-                    chk = chk_by_name[t.name]
-                    if not bool(jnp.all((kfault > 0) == ~chk.allowed)):
-                        raise AssertionError(
-                            "fused kernel and cached checker disagree for "
-                            f"tenant {t.name}")
+                with TraceAnnotation("serve.egress"):
+                    chk_by_name = {t.name: chk
+                                   for (t, _), chk in zip(active, checks)}
+                    for (t, _), kfault in zip(
+                            fusable, self._fused_step_egress(fusable)):
+                        chk = chk_by_name[t.name]
+                        self.host_reads += 1
+                        if not bool(jnp.all((kfault > 0) == ~chk.allowed)):
+                            raise AssertionError(
+                                "fused kernel and cached checker disagree "
+                                f"for tenant {t.name}")
         # phase 4: enforce verdicts, decode survivors
         for (t, _), chk in zip(active, checks):
-            if not bool(chk.allowed.all()):
-                fault = int(np.asarray(chk.fault).max())
+            with TraceAnnotation("serve.verdict", tenant=t.name):
+                self.host_reads += 1
+                allowed = bool(chk.allowed.all())
+                if not allowed:
+                    self.host_reads += 1
+                    fault = int(np.asarray(chk.fault).max())
+            if not allowed:
                 if fault == FAULT_DESYNC:
                     # fail-closed stall: the host lost BISnp events, so it
                     # denies everything until it resyncs.  The in-flight
@@ -331,24 +361,27 @@ class ServeEngine:
                 continue
             # the token fed this tick is the one served: its KV line is
             # what the check above released
-            for i in range(len(t.group)):
-                t.out[i].append(int(t.cur[i, 0]))
-            logits, t.cache = self._decode(
-                self.params, t.cache, t.cur,
-                jnp.asarray(t.pos, jnp.int32))
-            t.cur = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(
-                jnp.int32)
-            t.last_logits = logits[:, -1]
-            t.pos += 1
-            t.gen_left -= 1
-            self.steps += 1
-            retired = 0
-            if t.gen_left == 0:
-                t.done += [(g, o[len(g):])
-                           for g, o in zip(t.group, t.out)]
-                retired = len(t.group)
-                t.group = None
-                t.cache = None
+            with TraceAnnotation("serve.emit", tenant=t.name):
+                for i in range(len(t.group)):
+                    t.out[i].append(int(t.cur[i, 0]))
+                self.host_reads += len(t.group)
+            with TraceAnnotation("serve.decode", tenant=t.name):
+                logits, t.cache = self._decode(
+                    self.params, t.cache, t.cur,
+                    jnp.asarray(t.pos, jnp.int32))
+                t.cur = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(
+                    jnp.int32)
+                t.last_logits = logits[:, -1]
+                t.pos += 1
+                t.gen_left -= 1
+                self.steps += 1
+                retired = 0
+                if t.gen_left == 0:
+                    t.done += [(g, o[len(g):])
+                               for g, o in zip(t.group, t.out)]
+                    retired = len(t.group)
+                    t.group = None
+                    t.cache = None
             results[t.name] = {"aborted": False, "stalled": False,
                                "fault": FAULT_NONE, "retired": retired}
         return results

@@ -178,6 +178,19 @@ def causal_mask(sq: int, sk: int, *, window=-1, offset: int = 0):
     return m[None, None]
 
 
+def _kv_write(cache: KVCache, k, v, pos):
+    """k, v [B,S,Hkv,dh] written into the cache at sequence position `pos`,
+    under the `kv_write` scope that the device trace names them by."""
+    with jax.named_scope("kv_write"):
+        kc = jax.lax.dynamic_update_slice(
+            cache.k, k.transpose(0, 2, 1, 3).astype(cache.k.dtype),
+            (0, 0, pos, 0))
+        vc = jax.lax.dynamic_update_slice(
+            cache.v, v.transpose(0, 2, 1, 3).astype(cache.v.dtype),
+            (0, 0, pos, 0))
+    return kc, vc
+
+
 def attention(p, x, positions, *, theta: float = 10000.0,
               rotary_dim: int | None = None, window: int = -1,
               mrope_sections=None, cache: KVCache | None = None,
@@ -222,13 +235,7 @@ def attention(p, x, positions, *, theta: float = 10000.0,
             out = _sdpa(q, k, v, causal_mask(s, s, window=window))
         new_cache = None
     elif cache_pos is None:  # prefill
-        cap = cache.k.shape[2]
-        kc = jax.lax.dynamic_update_slice(
-            cache.k, k.transpose(0, 2, 1, 3).astype(cache.k.dtype),
-            (0, 0, 0, 0))
-        vc = jax.lax.dynamic_update_slice(
-            cache.v, v.transpose(0, 2, 1, 3).astype(cache.v.dtype),
-            (0, 0, 0, 0))
+        kc, vc = _kv_write(cache, k, v, 0)
         if s >= CHUNKED_THRESHOLD:
             out = chunked_attention(q, k, v, window=window, chunk=CHUNK)
         else:
@@ -237,12 +244,7 @@ def attention(p, x, positions, *, theta: float = 10000.0,
     else:  # decode: s == 1
         cap = cache.k.shape[2]
         pos = jnp.asarray(cache_pos, jnp.int32)
-        kc = jax.lax.dynamic_update_slice(
-            cache.k, k.transpose(0, 2, 1, 3).astype(cache.k.dtype),
-            (0, 0, pos, 0))
-        vc = jax.lax.dynamic_update_slice(
-            cache.v, v.transpose(0, 2, 1, 3).astype(cache.v.dtype),
-            (0, 0, pos, 0))
+        kc, vc = _kv_write(cache, k, v, pos)
         ki = jnp.arange(cap)
         w = jnp.asarray(window, jnp.int32)
         w_eff = jnp.where(w > 0, w, jnp.int32(2**30))

@@ -88,38 +88,46 @@ def init_moe_block(cfg: ArchConfig, key) -> dict:
     return p
 
 
+def _attention_block(cfg: ArchConfig, bp, x, positions, window, theta,
+                     cache: KVCache | None, cache_pos):
+    """Pre-norm self-attention with its residual, under the `attention`
+    scope that the device trace names its operations by."""
+    with jax.named_scope("attention"):
+        h = rms_norm(bp["ln1"], x)
+        att, new_cache = attention(
+            bp["attn"], h, positions, theta=theta,
+            rotary_dim=_rotary_dim(cfg), window=window,
+            mrope_sections=cfg.mrope_sections, cache=cache,
+            cache_pos=cache_pos)
+        return x + att, new_cache
+
+
 def apply_dense_block(cfg: ArchConfig, bp, x, positions, window, theta,
                       cache: KVCache | None, cache_pos):
-    h = rms_norm(bp["ln1"], x)
-    att, new_cache = attention(
-        bp["attn"], h, positions, theta=theta, rotary_dim=_rotary_dim(cfg),
-        window=window, mrope_sections=cfg.mrope_sections, cache=cache,
-        cache_pos=cache_pos)
-    x = x + att
-    h = rms_norm(bp["ln2"], x)
-    x = x + swiglu(bp["mlp"], h)
+    x, new_cache = _attention_block(cfg, bp, x, positions, window, theta,
+                                    cache, cache_pos)
+    with jax.named_scope("mlp"):
+        h = rms_norm(bp["ln2"], x)
+        x = x + swiglu(bp["mlp"], h)
     return x, new_cache, jnp.zeros((), jnp.float32)
 
 
 def apply_moe_block(cfg: ArchConfig, bp, x, positions, window, theta,
                     cache: KVCache | None, cache_pos):
-    h = rms_norm(bp["ln1"], x)
-    att, new_cache = attention(
-        bp["attn"], h, positions, theta=theta, rotary_dim=_rotary_dim(cfg),
-        window=window, mrope_sections=cfg.mrope_sections, cache=cache,
-        cache_pos=cache_pos)
-    x = x + att
-    h = rms_norm(bp["ln2"], x)
-    if cfg.moe_impl == "ep":
-        y, aux = moe_ffn_ep(bp["moe"], h, top_k=cfg.top_k,
-                            capacity_factor=cfg.capacity_factor,
-                            expert_axis=cfg.expert_axis)
-    else:
-        y, aux = moe_ffn(bp["moe"], h, top_k=cfg.top_k,
-                         capacity_factor=cfg.capacity_factor)
-    if "shared_mlp" in bp:
-        y = y + swiglu(bp["shared_mlp"], h)
-    x = x + y
+    x, new_cache = _attention_block(cfg, bp, x, positions, window, theta,
+                                    cache, cache_pos)
+    with jax.named_scope("mlp"):
+        h = rms_norm(bp["ln2"], x)
+        if cfg.moe_impl == "ep":
+            y, aux = moe_ffn_ep(bp["moe"], h, top_k=cfg.top_k,
+                                capacity_factor=cfg.capacity_factor,
+                                expert_axis=cfg.expert_axis)
+        else:
+            y, aux = moe_ffn(bp["moe"], h, top_k=cfg.top_k,
+                             capacity_factor=cfg.capacity_factor)
+        if "shared_mlp" in bp:
+            y = y + swiglu(bp["shared_mlp"], h)
+        x = x + y
     return x, new_cache, aux
 
 
