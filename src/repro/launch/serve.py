@@ -41,8 +41,10 @@ Observability (docs/observability.md): `step` writes one profiler span
 per phase (`serve.step`, `serve.gather`, `serve.prefill`, `serve.fence`,
 `serve.check`, `serve.egress`, then `serve.verdict`, `serve.emit` and
 `serve.decode` per tenant).  They cost about a microsecond each while no
-profiler runs; `host_reads` counts the step's blocking device-to-host
-reads.
+profiler runs; `host_reads` counts the results `step` reads back from the
+device.  Each tenant's served tokens leave the chip in one transfer,
+started when its token array is made and collected under `serve.emit`;
+`token_transfers` counts those transfers.
 """
 from __future__ import annotations
 
@@ -62,6 +64,15 @@ from repro.core.fabric import ShardedFabric
 from repro.core.table import PAGE_BYTES
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry
+
+
+def _next_tokens(logits: jax.Array) -> jax.Array:
+    """The greedy next token of each row, int32 [B, 1], with its copy to
+    the host already started: the device fills it as soon as the argmax
+    ends, so the emit that serves it finds it there."""
+    cur = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
+    cur.copy_to_host_async()
+    return cur
 
 
 @dataclass
@@ -117,10 +128,12 @@ class ServeEngine:
         self._decode = jax.jit(serve_decode)
         self.faults = 0
         self.steps = 0
-        # blocking device-to-host reads made by `step`: the fused-egress
-        # cross-check and the verdict of each tenant, the fault read of a
-        # deny, and each served token
+        # results `step` reads back from the device: each tenant's
+        # cross-check and verdict, the fault on a deny, and each served token
         self.host_reads = 0
+        # device-to-host transfers that carry served tokens: one per tenant
+        # emit, whatever the group's size
+        self.token_transfers = 0
         # fail-closed stalls: step ticks where a tenant's host was desynced
         # (lost BISnp events) and denied the batch WITHOUT aborting the
         # group — the tenant retries next tick and recovers after resync
@@ -227,7 +240,7 @@ class ServeEngine:
         t.group = group
         t.cache = cache
         t.out = [list(p) for p in group]
-        t.cur = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
+        t.cur = _next_tokens(logits)
         t.last_logits = logits[:, -1]
         t.plen = plen
         t.pos = plen
@@ -360,17 +373,18 @@ class ServeEngine:
                                    "fault": fault, "retired": 0}
                 continue
             # the token fed this tick is the one served: its KV line is
-            # what the check above released
+            # what the check above released.  One read of the copy started
+            # when the token was made; rows past the group are padding
             with TraceAnnotation("serve.emit", tenant=t.name):
-                for i in range(len(t.group)):
-                    t.out[i].append(int(t.cur[i, 0]))
+                for out, tok in zip(t.out, np.asarray(t.cur)[:, 0].tolist()):
+                    out.append(tok)
+                self.token_transfers += 1
                 self.host_reads += len(t.group)
             with TraceAnnotation("serve.decode", tenant=t.name):
                 logits, t.cache = self._decode(
                     self.params, t.cache, t.cur,
                     jnp.asarray(t.pos, jnp.int32))
-                t.cur = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(
-                    jnp.int32)
+                t.cur = _next_tokens(logits)
                 t.last_logits = logits[:, -1]
                 t.pos += 1
                 t.gen_left -= 1

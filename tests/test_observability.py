@@ -7,6 +7,7 @@ import glob
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs import ARCHS, smoke_config
 from repro.core import ShardedFabric, pack_ext_addr
@@ -100,3 +101,34 @@ def test_the_permission_cache_probe_carries_its_name():
         table, jnp.zeros((4,), jnp.uint32), ext, jnp.zeros((4,), bool),
         make_perm_cache(1 << 14, epoch=0)).as_text(debug_info=True)
     assert "permcache_probe" in text
+
+
+def test_served_tokens_leave_the_chip_in_one_transfer_a_tenant():
+    cfg = smoke_config(ARCHS["qwen1.5-0.5b"])
+    params = registry.init_params(cfg, jax.random.key(0))
+    engine = ServeEngine(cfg, params, batch=2, cap=12, fused_egress=True)
+    rng = np.random.default_rng(0)
+    for name, host, n in (("a", 0, 2), ("b", 0, 2), ("c", 1, 1)):
+        engine.add_tenant(name, host_id=host)
+        for _ in range(n):
+            engine.submit(name, rng.integers(3, cfg.vocab - 1, 8))
+
+    def tick():
+        reads, moved = engine.host_reads, engine.token_transfers
+        res = engine.step(gen=4)
+        return (res, engine.token_transfers - moved,
+                engine.host_reads - reads)
+
+    # every tenant: cross-check, verdict and one read a served token
+    _, moved, reads = tick()
+    assert (moved, reads) == (3, (2 + 2) + (2 + 2) + (2 + 1))
+    engine.revoke("a")
+    engine.fabric.crash_host(1)
+    res, moved, reads = tick()
+    assert res["a"]["aborted"] and res["c"]["stalled"]
+    # b alone serves; the denied a reads its cross-check, verdict and fault
+    assert (moved, reads) == (1, (2 + 2) + 3)
+    engine.fabric.rejoin_host(1)
+    res, moved, reads = tick()
+    assert not res["c"]["stalled"] and "a" not in res
+    assert (moved, reads) == (2, (2 + 2) + (2 + 1))

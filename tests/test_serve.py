@@ -1,5 +1,8 @@
 """Serving engine: batched multi-tenant decode + live revocation."""
+import functools
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -7,11 +10,18 @@ from repro.configs import ARCHS, smoke_config
 from repro.launch.serve import ServeEngine
 from repro.models import registry
 
+PLEN, GEN = 10, 4
+
 
 @pytest.fixture(scope="module")
-def engine():
+def model():
     cfg = smoke_config(ARCHS["qwen1.5-0.5b"])
-    params = registry.init_params(cfg, jax.random.key(0))
+    return cfg, registry.init_params(cfg, jax.random.key(0))
+
+
+@pytest.fixture(scope="module")
+def engine(model):
+    cfg, params = model
     e = ServeEngine(cfg, params, batch=2, cap=24)
     e.add_tenant("a", host_id=0)
     e.add_tenant("b", host_id=1)
@@ -48,3 +58,79 @@ def test_revocation_aborts_decoding(engine):
     engine.submit("a", rng.integers(3, engine.cfg.vocab - 1, 12))
     r2 = engine.run_tenant("a", gen=2)
     assert not r2["aborted"]
+
+
+def _greedy(cfg, params, group, batch, gen):
+    """Each prompt's greedy tokens, one `registry.decode_step` at a time
+    after a prefill of the group padded to `batch` rows (as the engine
+    lays it out); the padding rows' tokens are dropped."""
+    decode = jax.jit(functools.partial(registry.decode_step, cfg))
+    plen = max(len(p) for p in group)
+    toks = np.full((batch, plen), 2, np.int32)
+    for i, p in enumerate(group):
+        toks[i, :len(p)] = p
+    logits, cache = registry.prefill(
+        cfg, params, {"tokens": jnp.asarray(toks)}, cache_dtype=cfg.pdtype,
+        cap=plen + gen)
+    served = []
+    for pos in range(plen, plen + gen):
+        cur = np.argmax(np.asarray(logits[:, -1]), -1).astype(np.int32)
+        served.append(cur)
+        logits, cache = decode(params, cache, jnp.asarray(cur[:, None]),
+                               jnp.asarray(pos, jnp.int32))
+    return np.stack(served, 1)[:len(group)].tolist()
+
+
+def _prompts(cfg, seed, n):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(3, cfg.vocab - 1, PLEN).astype(np.int32)
+            for _ in range(n)]
+
+
+def test_served_tokens_are_the_greedy_tokens_and_padding_is_never_served(
+        model):
+    cfg, params = model
+    e = ServeEngine(cfg, params, batch=2, cap=PLEN + GEN)
+    t = e.add_tenant("a", host_id=0)
+    prompts = _prompts(cfg, 7, 3)          # a group of 2, then a group of 1
+    for p in prompts:
+        e.submit("a", p)
+    served_rows = []
+    while e.has_work():
+        out = t.out if t.group is not None else None
+        before = [len(o) for o in out or ()]
+        e.step(gen=GEN)
+        served_rows.append(len(t.out))
+        if t.out is out:                   # one more token on every row
+            assert [len(o) for o in out] == [n + 1 for n in before]
+    assert served_rows == [2] * GEN + [1] * GEN
+    assert len(t.done) == 3 and not t.aborted
+    want = (_greedy(cfg, params, prompts[:2], 2, GEN)
+            + _greedy(cfg, params, prompts[2:], 2, GEN))
+    for (prompt, generated), p, w in zip(t.done, prompts, want):
+        assert list(prompt) == list(p)
+        assert generated == w
+
+
+def test_a_tenant_revoked_mid_group_serves_nothing_from_its_deny(model):
+    cfg, params = model
+    e = ServeEngine(cfg, params, batch=2, cap=PLEN + GEN)
+    a = e.add_tenant("a", host_id=0)
+    c = e.add_tenant("c", host_id=0)       # co-resident on a's host
+    pa, pc = _prompts(cfg, 8, 2), _prompts(cfg, 9, 2)
+    for p in pa:
+        e.submit("a", p)
+    for p in pc:
+        e.submit("c", p)
+    for _ in range(2):
+        e.step(gen=GEN)
+    a_out, c_out = [list(o) for o in a.out], [len(o) for o in c.out]
+    e.revoke("a")
+    res = e.step(gen=GEN)
+    assert res["a"]["aborted"] and not res["c"]["aborted"]
+    assert a.out == a_out and [len(o) for o in c.out] == [n + 1 for n in c_out]
+    while e.has_work():
+        assert not e.step(gen=GEN).get("a")
+    assert [list(p) for p in a.aborted] == [list(p) for p in pa]
+    assert not a.done and a.out == a_out
+    assert [g for _, g in c.done] == _greedy(cfg, params, pc, 2, GEN)
