@@ -1,5 +1,6 @@
 """Assigned architecture configs (one module per arch) + registry."""
-from .base import SHAPES, ArchConfig, ShapeConfig, smoke_config
+from .base import SHAPES, ArchConfig, ShapeConfig, YarnScaling, smoke_config
+from .deepseek_v2_lite import CONFIG as deepseek_v2_lite
 from .falcon_mamba_7b import CONFIG as falcon_mamba_7b
 from .gemma3_1b import CONFIG as gemma3_1b
 from .glm4_9b import CONFIG as glm4_9b
@@ -16,7 +17,7 @@ ARCHS: dict[str, ArchConfig] = {
     for c in [
         qwen15_05b, glm4_9b, qwen3_4b, gemma3_1b, zamba2_12b,
         llama4_maverick_400b_a17b, olmoe_1b_7b, seamless_m4t_medium,
-        qwen2_vl_7b, falcon_mamba_7b,
+        qwen2_vl_7b, falcon_mamba_7b, deepseek_v2_lite,
     ]
 }
 

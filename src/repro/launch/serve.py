@@ -44,7 +44,10 @@ per phase (`serve.step`, `serve.gather`, `serve.prefill`, `serve.fence`,
 profiler runs; `host_reads` counts the results `step` reads back from the
 device.  Each tenant's served tokens leave the chip in one transfer,
 started when its token array is made and collected under `serve.emit`;
-`token_transfers` counts those transfers.
+`token_transfers` counts those transfers.  A model that holds a share of
+its experts (`experts_held`) also sums its routing counts on the device,
+per tenant, inside the decode step: `expert_slots` and `experts_hit` read
+nothing back until the caller converts them.
 """
 from __future__ import annotations
 
@@ -98,6 +101,10 @@ class Tenant:
     # logits [B, V] behind the group's newest token (prefill, then each
     # decode step): what a reference forward pass is compared with
     last_logits: jax.Array | None = None
+    # int32[2] on the device, models with held experts only: held (token,
+    # expert) slots computed and held experts hit, summed over layers and
+    # decode steps
+    routed: jax.Array | None = None
 
 
 class ServeEngine:
@@ -122,8 +129,14 @@ class ServeEngine:
         self.fm = self.fabric.fm
         self.tenants: dict[str, Tenant] = {}
 
-        def serve_decode(p, c, t, pos):
-            return registry.decode_step(cfg, p, c, t, pos)
+        if cfg.experts_held:
+            def serve_decode(p, c, t, pos, routed):
+                logits, c, counts = registry.decode_step_routed(
+                    cfg, p, c, t, pos)
+                return logits, c, routed + counts
+        else:
+            def serve_decode(p, c, t, pos):
+                return registry.decode_step(cfg, p, c, t, pos)
 
         self._decode = jax.jit(serve_decode)
         self.faults = 0
@@ -140,6 +153,18 @@ class ServeEngine:
         self.stalls = 0
 
     # -- observability ---------------------------------------------------------
+    @property
+    def expert_slots(self) -> jax.Array:
+        """Held (token, expert) slots computed in decode steps, over the
+        admitted tenants: a device scalar (models with held experts)."""
+        return sum(t.routed[0] for t in self.tenants.values())
+
+    @property
+    def experts_hit(self) -> jax.Array:
+        """Held experts with at least one slot, summed over layers and
+        decode steps and the admitted tenants: a device scalar."""
+        return sum(t.routed[1] for t in self.tenants.values())
+
     @property
     def bisnp_events(self) -> int:
         """Back-invalidates observed across every enrolled host."""
@@ -191,6 +216,8 @@ class ServeEngine:
             start_page=start)
         t = Tenant(name, hwpid, host_id,
                    kv_start_page=start, kv_n_pages=n_pages)
+        if self.cfg.experts_held:
+            t.routed = jnp.zeros((2,), jnp.int32)
         self.tenants[name] = t
         return t
 
@@ -381,9 +408,13 @@ class ServeEngine:
                 self.token_transfers += 1
                 self.host_reads += len(t.group)
             with TraceAnnotation("serve.decode", tenant=t.name):
-                logits, t.cache = self._decode(
-                    self.params, t.cache, t.cur,
-                    jnp.asarray(t.pos, jnp.int32))
+                pos = jnp.asarray(t.pos, jnp.int32)
+                if t.routed is None:
+                    logits, t.cache = self._decode(
+                        self.params, t.cache, t.cur, pos)
+                else:
+                    logits, t.cache, t.routed = self._decode(
+                        self.params, t.cache, t.cur, pos, t.routed)
                 t.cur = _next_tokens(logits)
                 t.last_logits = logits[:, -1]
                 t.pos += 1

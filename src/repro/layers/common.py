@@ -35,11 +35,13 @@ def rope_frequencies(rotary_dim: int, theta):
     return jnp.asarray(theta, jnp.float32) ** (-expo)
 
 
-def apply_rope(x, positions, *, theta=10000.0, rotary_dim: int | None = None):
-    """x: [B, S, H, Dh]; positions: [B, S] (int). Partial rotary supported."""
+def apply_rope(x, positions, *, theta=10000.0, rotary_dim: int | None = None,
+               inv_freq=None):
+    """x: [B, S, H, Dh]; positions: [B, S] (int). Partial rotary supported.
+    `inv_freq` [rd / 2], where given, replaces theta's frequencies (YaRN)."""
     dh = x.shape[-1]
     rd = rotary_dim or dh
-    inv = rope_frequencies(rd, theta)
+    inv = rope_frequencies(rd, theta) if inv_freq is None else inv_freq
     ang = positions[..., None].astype(jnp.float32) * inv  # [B,S,rd/2]
     sin = jnp.sin(ang)[:, :, None, :]
     cos = jnp.cos(ang)[:, :, None, :]

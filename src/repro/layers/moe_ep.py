@@ -51,13 +51,14 @@ def _shmap(body, mesh, in_specs, out_specs):
 # local (per-shard) routing helpers — plain jnp, shard_map-safe
 # ---------------------------------------------------------------------------
 
-def _route(xt, router, top_k: int):
+def _route(xt, router, top_k: int, norm_topk_prob: bool = True):
     """[t, D] -> (gate_vals [t,K], gate_idx [t,K], aux scalar)."""
     logits = xt.astype(router.dtype) @ router                 # [t, E]
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, gate_idx = jax.lax.top_k(probs, top_k)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
+    if norm_topk_prob:
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9)
     e = router.shape[1]
     onehot = jax.nn.one_hot(gate_idx, e, dtype=jnp.float32)
     frac_tok = onehot.sum(axis=(0, 1)) / (xt.shape[0] * top_k)
@@ -106,7 +107,8 @@ def _expert_ffn(expert_in, wg, wu, wd):
 # ---------------------------------------------------------------------------
 
 def _moe_block_model_axis(xt, router, wg, wu, wd, *, top_k: int, cap: int,
-                          n_experts: int, model_axis: str):
+                          n_experts: int, model_axis: str,
+                          norm_topk_prob: bool = True):
     """shard_map body.  xt [t_loc, D] (same copy on every model column);
     wg/wu/wd [E_loc, ...] (this column's experts)."""
     t = xt.shape[0]
@@ -114,7 +116,7 @@ def _moe_block_model_axis(xt, router, wg, wu, wd, *, top_k: int, cap: int,
     j = jax.lax.axis_index(model_axis) if model_axis else jnp.int32(0)
     e0 = j * e_loc
 
-    gate_vals, gate_idx, aux = _route(xt, router, top_k)
+    gate_vals, gate_idx, aux = _route(xt, router, top_k, norm_topk_prob)
     pos, valid = _positions(gate_idx, n_experts, cap)
     token_idx = _scatter_token_idx(gate_idx, pos, valid, n_experts, cap, t)
     token_idx = jax.lax.dynamic_slice(token_idx, (e0, 0), (e_loc, cap))
@@ -148,7 +150,7 @@ def _moe_block_model_axis(xt, router, wg, wu, wd, *, top_k: int, cap: int,
 
 def _moe_block_data_axis(xt, router, wg, wu, wd, *, top_k: int, cap: int,
                          n_experts: int, data_axes: tuple,
-                         model_axis: str):
+                         model_axis: str, norm_topk_prob: bool = True):
     """shard_map body.  xt [t_loc, D] per data shard (replicated over
     model); wg/wu/wd [E_loc, D, F_loc] (this data-row's experts, this
     model-column's FFN slice)."""
@@ -156,7 +158,7 @@ def _moe_block_data_axis(xt, router, wg, wu, wd, *, top_k: int, cap: int,
     e_loc = wg.shape[0]
     rows = n_experts // e_loc                     # data-axis size
 
-    gate_vals, gate_idx, aux = _route(xt, router, top_k)
+    gate_vals, gate_idx, aux = _route(xt, router, top_k, norm_topk_prob)
     dest = gate_idx // e_loc                      # [t, K] home row per slot
 
     # per-destination-row send positions (capacity per row)
@@ -227,7 +229,7 @@ def _moe_block_data_axis(xt, router, wg, wu, wd, *, top_k: int, cap: int,
 # ---------------------------------------------------------------------------
 
 def moe_ffn_ep(p, x, *, top_k: int, capacity_factor: float = 1.25,
-               expert_axis: str = "model"):
+               expert_axis: str = "model", norm_topk_prob: bool = True):
     """Drop-in for moe.moe_ffn (same params pytree, same returns), running
     the sorted-dispatch expert-parallel path under the ambient mesh.  Falls
     back to a meshless local computation when no mesh context is active
@@ -242,7 +244,7 @@ def moe_ffn_ep(p, x, *, top_k: int, capacity_factor: float = 1.25,
         y, aux = _moe_block_model_axis(
             x.reshape(t, d), p["router"], p["w_gate"], p["w_up"],
             p["w_down"], top_k=top_k, cap=cap, n_experts=e,
-            model_axis=None)  # type: ignore[arg-type]
+            model_axis=None, norm_topk_prob=norm_topk_prob)  # type: ignore[arg-type]
         return y.reshape(b, s, d), aux
 
     data_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -259,7 +261,7 @@ def moe_ffn_ep(p, x, *, top_k: int, capacity_factor: float = 1.25,
     if expert_axis == "model" and model_axis and e % msize == 0 and batch_ok:
         body = functools.partial(
             _moe_block_model_axis, top_k=top_k, cap=cap, n_experts=e,
-            model_axis=model_axis)
+            model_axis=model_axis, norm_topk_prob=norm_topk_prob)
         y, aux = _shmap(
             body, mesh,
             in_specs=(P(data_axes, None), P(None, None),
@@ -277,7 +279,7 @@ def moe_ffn_ep(p, x, *, top_k: int, capacity_factor: float = 1.25,
             _moe_block_data_axis, top_k=top_k,
             cap=max(int(np.ceil(t_loc * capacity_factor * top_k / e)), 1),
             n_experts=e, data_axes=data_axes,
-            model_axis=model_axis or ())
+            model_axis=model_axis or (), norm_topk_prob=norm_topk_prob)
         y, aux = _shmap(
             body, mesh,
             in_specs=(P(data_axes, None), P(None, None),
@@ -290,4 +292,5 @@ def moe_ffn_ep(p, x, *, top_k: int, capacity_factor: float = 1.25,
 
     # layout not expressible on this mesh: einsum fallback
     from repro.layers.moe import moe_ffn
-    return moe_ffn(p, x, top_k=top_k, capacity_factor=capacity_factor)
+    return moe_ffn(p, x, top_k=top_k, capacity_factor=capacity_factor,
+                   norm_topk_prob=norm_topk_prob)

@@ -2,9 +2,11 @@
 
 One scan-over-layers implementation covers qwen1.5, glm4, qwen3, gemma3
 (per-layer window/theta as scan inputs), olmoe (MoE every layer), llama4
-(scan over dense+MoE *pairs* with a shared expert) and qwen2-vl (M-RoPE +
-pre-embedded vision patches).  Stacked per-layer params keep the HLO size
-O(1) in depth — essential for 64-layer archs on the 512-device dry-run.
+(scan over dense+MoE *pairs* with a shared expert), qwen2-vl (M-RoPE +
+pre-embedded vision patches) and deepseek-v2-lite (latent attention, a
+leading dense layer ahead of the scan, held experts dropless).  Stacked
+per-layer params keep the HLO size O(1) in depth — essential for 64-layer
+archs on the 512-device dry-run.
 """
 from __future__ import annotations
 
@@ -29,7 +31,8 @@ from repro.layers.common import (
     swiglu,
     unembed,
 )
-from repro.layers.moe import init_moe, moe_ffn
+from repro.layers.mla import init_mla, init_mla_cache, mla_attention
+from repro.layers.moe import init_moe, moe_ffn, moe_ffn_held
 from repro.layers.moe_ep import moe_ffn_ep
 
 
@@ -60,13 +63,19 @@ def _rotary_dim(cfg: ArchConfig) -> int:
 # blocks
 # ---------------------------------------------------------------------------
 
+def _init_attention(cfg: ArchConfig, key) -> dict:
+    if cfg.mla:
+        return init_mla(cfg, key)
+    return init_attention(cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
+                          cfg.head_dim, cfg.pdtype, key,
+                          qkv_bias=cfg.qkv_bias, qk_norm=cfg.qk_norm)
+
+
 def init_dense_block(cfg: ArchConfig, key) -> dict:
     k1, k2 = jax.random.split(key)
     return {
         "ln1": init_rms_norm(cfg.d_model, cfg.pdtype),
-        "attn": init_attention(cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
-                               cfg.head_dim, cfg.pdtype, k1,
-                               qkv_bias=cfg.qkv_bias, qk_norm=cfg.qk_norm),
+        "attn": _init_attention(cfg, k1),
         "ln2": init_rms_norm(cfg.d_model, cfg.pdtype),
         "mlp": init_swiglu(cfg.d_model, cfg.d_ff, cfg.pdtype, k2),
     }
@@ -76,15 +85,15 @@ def init_moe_block(cfg: ArchConfig, key) -> dict:
     k1, k2, k3 = jax.random.split(key, 3)
     p = {
         "ln1": init_rms_norm(cfg.d_model, cfg.pdtype),
-        "attn": init_attention(cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
-                               cfg.head_dim, cfg.pdtype, k1,
-                               qkv_bias=cfg.qkv_bias, qk_norm=cfg.qk_norm),
+        "attn": _init_attention(cfg, k1),
         "ln2": init_rms_norm(cfg.d_model, cfg.pdtype),
         "moe": init_moe(cfg.d_model, cfg.expert_d_ff or cfg.d_ff,
-                        cfg.n_experts, cfg.pdtype, k2),
+                        cfg.n_experts, cfg.pdtype, k2,
+                        n_held=cfg.n_held if cfg.experts_held else None),
     }
     if cfg.shared_expert:
-        p["shared_mlp"] = init_swiglu(cfg.d_model, cfg.d_ff, cfg.pdtype, k3)
+        p["shared_mlp"] = init_swiglu(
+            cfg.d_model, cfg.shared_expert_d_ff or cfg.d_ff, cfg.pdtype, k3)
     return p
 
 
@@ -94,11 +103,15 @@ def _attention_block(cfg: ArchConfig, bp, x, positions, window, theta,
     scope that the device trace names its operations by."""
     with jax.named_scope("attention"):
         h = rms_norm(bp["ln1"], x)
-        att, new_cache = attention(
-            bp["attn"], h, positions, theta=theta,
-            rotary_dim=_rotary_dim(cfg), window=window,
-            mrope_sections=cfg.mrope_sections, cache=cache,
-            cache_pos=cache_pos)
+        if cfg.mla:
+            att, new_cache = mla_attention(cfg, bp["attn"], h, positions,
+                                           cache=cache, cache_pos=cache_pos)
+        else:
+            att, new_cache = attention(
+                bp["attn"], h, positions, theta=theta,
+                rotary_dim=_rotary_dim(cfg), window=window,
+                mrope_sections=cfg.mrope_sections, cache=cache,
+                cache_pos=cache_pos)
         return x + att, new_cache
 
 
@@ -118,15 +131,24 @@ def apply_moe_block(cfg: ArchConfig, bp, x, positions, window, theta,
                                     cache, cache_pos)
     with jax.named_scope("mlp"):
         h = rms_norm(bp["ln2"], x)
-        if cfg.moe_impl == "ep":
+        if cfg.experts_held:
+            y, lb, counts = moe_ffn_held(
+                bp["moe"], h, top_k=cfg.top_k, held=cfg.experts_held,
+                norm_topk_prob=cfg.norm_topk_prob,
+                routed_scaling_factor=cfg.routed_scaling_factor)
+            aux = (lb, counts)
+        elif cfg.moe_impl == "ep":
             y, aux = moe_ffn_ep(bp["moe"], h, top_k=cfg.top_k,
                                 capacity_factor=cfg.capacity_factor,
-                                expert_axis=cfg.expert_axis)
+                                expert_axis=cfg.expert_axis,
+                                norm_topk_prob=cfg.norm_topk_prob)
         else:
             y, aux = moe_ffn(bp["moe"], h, top_k=cfg.top_k,
-                             capacity_factor=cfg.capacity_factor)
+                             capacity_factor=cfg.capacity_factor,
+                             norm_topk_prob=cfg.norm_topk_prob)
         if "shared_mlp" in bp:
-            y = y + swiglu(bp["shared_mlp"], h)
+            with jax.named_scope("shared_experts"):
+                y = y + swiglu(bp["shared_mlp"], h)
         x = x + y
     return x, new_cache, aux
 
@@ -136,8 +158,10 @@ def apply_moe_block(cfg: ArchConfig, bp, x, positions, window, theta,
 # ---------------------------------------------------------------------------
 
 def n_units(cfg: ArchConfig) -> int:
-    return cfg.n_layers // 2 if (cfg.family == "moe" and cfg.moe_every == 2) \
-        else cfg.n_layers
+    """Units of the scan; `first_k_dense` leading layers run ahead of it."""
+    layers = cfg.n_layers - cfg.first_k_dense
+    return layers // 2 if (cfg.family == "moe" and cfg.moe_every == 2) \
+        else layers
 
 
 def layers_per_unit(cfg: ArchConfig) -> int:
@@ -155,6 +179,8 @@ def init_unit(cfg: ArchConfig, key) -> dict:
 
 
 def init_unit_cache(cfg: ArchConfig, batch: int, cap: int, dtype) -> Any:
+    if cfg.mla:
+        return init_mla_cache(cfg, batch, cap, dtype)
     mk = lambda: init_kv_cache(batch, cfg.n_kv_heads, cap, cfg.head_dim, dtype)
     if cfg.family == "moe" and cfg.moe_every == 2:
         return {"dense": mk(), "moe": mk()}
@@ -187,13 +213,19 @@ def init_params(cfg: ArchConfig, key) -> dict:
     ku, ke, kh = jax.random.split(key, 3)
     unit_keys = jax.random.split(ku, n_units(cfg))
     units = jax.vmap(lambda k: init_unit(cfg, k))(unit_keys)
-    return {
+    params = {
         "embed": init_embed(cfg.vocab_padded, cfg.d_model, cfg.pdtype, ke),
         "units": units,
         "final_norm": init_rms_norm(cfg.d_model, cfg.pdtype),
         "head": init_head(cfg.vocab_padded, cfg.d_model, cfg.pdtype, kh,
                           tied=cfg.tie_embeddings),
     }
+    if cfg.first_k_dense:
+        lead_keys = jax.random.split(jax.random.fold_in(ku, 1),
+                                     cfg.first_k_dense)
+        params["lead"] = jax.vmap(lambda k: init_dense_block(cfg, k))(
+            lead_keys)
+    return params
 
 
 # ---------------------------------------------------------------------------
@@ -212,9 +244,10 @@ def _embed_inputs(cfg: ArchConfig, params, tokens, vision_embeds):
     return x
 
 
-def _run_units(cfg: ArchConfig, params, x, positions, cache, cache_pos):
-    """Scan the stacked units.  cache: stacked [U, ...] pytree or None."""
-    windows, thetas = layer_schedule(cfg, n_units(cfg))
+def _scan_layers(cfg: ArchConfig, apply, stacked, x, positions, cache,
+                 cache_pos, aux0):
+    """Scan `apply` over stacked per-layer params (and cache, or None)."""
+    windows, thetas = layer_schedule(cfg, jax.tree.leaves(stacked)[0].shape[0])
 
     def body(carry, xs):
         xc, aux = carry
@@ -223,17 +256,35 @@ def _run_units(cfg: ArchConfig, params, x, positions, cache, cache_pos):
             c = None
         else:
             up, w, th, c = xs
-        xc, new_c, a = apply_unit(cfg, up, xc, positions, w, th, c, cache_pos)
-        return (xc, aux + a), new_c
+        xc, new_c, a = apply(cfg, up, xc, positions, w, th, c, cache_pos)
+        return (xc, jax.tree.map(jnp.add, aux, a)), new_c
 
     from repro.layers.common import apply_remat
     body = apply_remat(body, cfg.remat)
-    xs = (params["units"], windows, thetas) if cache is None else \
-        (params["units"], windows, thetas, cache)
-    (x, aux), new_cache = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), xs,
-        unroll=cfg.scan_unroll)
+    xs = (stacked, windows, thetas) if cache is None else \
+        (stacked, windows, thetas, cache)
+    (x, aux), new_cache = jax.lax.scan(body, (x, aux0), xs,
+                                       unroll=cfg.scan_unroll)
     return x, aux, new_cache
+
+
+def _run_units(cfg: ArchConfig, params, x, positions, cache, cache_pos):
+    """The leading dense layers, if any, then the scan over the stacked
+    units.  cache: stacked [U, ...] pytree ({"lead", "units"} with leading
+    dense layers) or None.  aux: the balance loss, with held experts
+    (loss, routing counts int32[2])."""
+    zero = jnp.zeros((), jnp.float32)
+    aux0 = (zero, jnp.zeros((2,), jnp.int32)) if cfg.experts_held else zero
+    if not cfg.first_k_dense:
+        return _scan_layers(cfg, apply_unit, params["units"], x, positions,
+                            cache, cache_pos, aux0)
+    x, _, lead = _scan_layers(cfg, apply_dense_block, params["lead"], x,
+                              positions, None if cache is None
+                              else cache["lead"], cache_pos, zero)
+    x, aux, units = _scan_layers(cfg, apply_unit, params["units"], x,
+                                 positions, None if cache is None
+                                 else cache["units"], cache_pos, aux0)
+    return x, aux, None if cache is None else {"lead": lead, "units": units}
 
 
 def forward(cfg: ArchConfig, params, tokens, *, vision_embeds=None,
@@ -247,6 +298,8 @@ def forward(cfg: ArchConfig, params, tokens, *, vision_embeds=None,
             positions = jnp.broadcast_to(positions[None], (3, b, s))
     x = _embed_inputs(cfg, params, tokens, vision_embeds)
     x, aux, _ = _run_units(cfg, params, x, positions, None, None)
+    if cfg.experts_held:
+        aux = aux[0]
     x = rms_norm(params["final_norm"], x)
     logits = unembed(params["embed"], params["head"], x,
                      tied=cfg.tie_embeddings)
@@ -264,11 +317,17 @@ def loss_fn(cfg: ArchConfig, params, batch):
 # -- serving ----------------------------------------------------------------
 
 def init_cache(cfg: ArchConfig, batch: int, cap: int, dtype=jnp.bfloat16):
-    """Stacked [U, ...] KV cache."""
+    """Stacked [U, ...] KV cache; {"lead", "units"} with leading dense
+    layers."""
     unit = init_unit_cache(cfg, batch, cap, dtype)
-    return jax.tree.map(
-        lambda leaf: jnp.broadcast_to(
-            leaf[None], (n_units(cfg),) + leaf.shape), unit)
+
+    def stack(n):
+        return jax.tree.map(
+            lambda leaf: jnp.broadcast_to(leaf[None], (n,) + leaf.shape),
+            unit)
+    if cfg.first_k_dense:
+        return {"lead": stack(cfg.first_k_dense), "units": stack(n_units(cfg))}
+    return stack(n_units(cfg))
 
 
 def prefill(cfg: ArchConfig, params, tokens, *, vision_embeds=None,
@@ -294,6 +353,19 @@ def prefill(cfg: ArchConfig, params, tokens, *, vision_embeds=None,
 def decode_step(cfg: ArchConfig, params, cache, tokens, pos):
     """One serving step: tokens [B,1] at absolute position `pos` (scalar),
     attending over cache[<= pos].  Returns (logits [B,1,V], new_cache)."""
+    logits, new_cache, _ = _decode(cfg, params, cache, tokens, pos)
+    return logits, new_cache
+
+
+def decode_step_routed(cfg: ArchConfig, params, cache, tokens, pos):
+    """`decode_step` of a model with held experts, also returning the
+    step's routing counts int32[2] summed over layers: the held (token,
+    expert) slots computed and the held experts with at least one slot."""
+    logits, new_cache, aux = _decode(cfg, params, cache, tokens, pos)
+    return logits, new_cache, aux[1]
+
+
+def _decode(cfg: ArchConfig, params, cache, tokens, pos):
     b, s = tokens.shape
     assert s == 1
     pos = jnp.asarray(pos, jnp.int32)
@@ -301,8 +373,8 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, pos):
     if cfg.mrope_sections is not None:
         positions = jnp.broadcast_to(positions[None], (3, b, 1))
     x = _embed_inputs(cfg, params, tokens, None)
-    x, _, new_cache = _run_units(cfg, params, x, positions, cache, pos)
+    x, aux, new_cache = _run_units(cfg, params, x, positions, cache, pos)
     x = rms_norm(params["final_norm"], x)
     logits = unembed(params["embed"], params["head"], x,
                      tied=cfg.tie_embeddings)
-    return logits, new_cache
+    return logits, new_cache, aux

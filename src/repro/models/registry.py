@@ -57,6 +57,12 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, pos):
     return model_module(cfg).decode_step(cfg, params, cache, tokens, pos)
 
 
+def decode_step_routed(cfg: ArchConfig, params, cache, tokens, pos):
+    """decode_step of a model with held experts, with its routing counts."""
+    return model_module(cfg).decode_step_routed(cfg, params, cache, tokens,
+                                                pos)
+
+
 def cache_shapes(cfg: ArchConfig, batch: int, cap: int,
                  dtype=jnp.bfloat16):
     """ShapeDtypeStruct tree of the serving cache."""
