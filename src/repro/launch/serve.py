@@ -44,7 +44,9 @@ per phase (`serve.step`, `serve.gather`, `serve.prefill`, `serve.fence`,
 profiler runs; `host_reads` counts the results `step` reads back from the
 device.  Each tenant's served tokens leave the chip in one transfer,
 started when its token array is made and collected under `serve.emit`;
-`token_transfers` counts those transfers.  A model that holds a share of
+`token_transfers` counts those transfers.  The decode step is given each
+tenant's cache to update in place (donated); `cache_donations` counts the
+launches that consumed it so.  A model that holds a share of
 its experts (`experts_held`) also sums its routing counts on the device,
 per tenant, inside the decode step: `expert_slots` and `experts_hit` read
 nothing back until the caller converts them.
@@ -76,6 +78,12 @@ def _next_tokens(logits: jax.Array) -> jax.Array:
     cur = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
     cur.copy_to_host_async()
     return cur
+
+
+def _consumed(cache) -> bool:
+    """Whether every buffer of `cache` has been deleted, as a donated input
+    is once its launch is dispatched; read on the host, no device sync."""
+    return all(leaf.is_deleted() for leaf in jax.tree.leaves(cache))
 
 
 @dataclass
@@ -138,9 +146,14 @@ class ServeEngine:
             def serve_decode(p, c, t, pos):
                 return registry.decode_step(cfg, p, c, t, pos)
 
-        self._decode = jax.jit(serve_decode)
+        # the cache is donated: each step updates its one buffer in place
+        self._decode = jax.jit(serve_decode, donate_argnums=(1,))
         self.faults = 0
         self.steps = 0
+        # decode launches whose input cache was consumed in place (its
+        # buffers deleted once the launch is dispatched): in steady state
+        # one a step
+        self.cache_donations = 0
         # results `step` reads back from the device: each tenant's
         # cross-check and verdict, the fault on a deny, and each served token
         self.host_reads = 0
@@ -409,12 +422,14 @@ class ServeEngine:
                 self.host_reads += len(t.group)
             with TraceAnnotation("serve.decode", tenant=t.name):
                 pos = jnp.asarray(t.pos, jnp.int32)
+                cache = t.cache
                 if t.routed is None:
                     logits, t.cache = self._decode(
-                        self.params, t.cache, t.cur, pos)
+                        self.params, cache, t.cur, pos)
                 else:
                     logits, t.cache, t.routed = self._decode(
-                        self.params, t.cache, t.cur, pos, t.routed)
+                        self.params, cache, t.cur, pos, t.routed)
+                self.cache_donations += _consumed(cache)
                 t.cur = _next_tokens(logits)
                 t.last_logits = logits[:, -1]
                 t.pos += 1

@@ -50,9 +50,28 @@ def init_attention(d: int, n_heads: int, n_kv: int, head_dim: int, dtype, key,
     return p
 
 
+# Positions in one tile of a cache.  The TPU compiler lays a cache out
+# with its positions along the minor dimension, in tiles this wide.
+# Updated one row at a time, the cache is re-laid-out around the write, a
+# copy the size of the cache every step; so a one-token write reads,
+# changes and writes back the whole tile that holds the position, in
+# place (`cache_put`), and every cache is made a whole number of tiles
+# long (`cache_len`) so that each such tile lies inside it.  The chip
+# writes a block that is not one whole tile piecemeal, several times
+# slower.
+CACHE_TILE = 128
+
+
+def cache_len(cap: int) -> int:
+    """`cap` positions rounded up to whole tiles of `CACHE_TILE`: the
+    length of a cache that decode writes one token at a time."""
+    return -(-cap // CACHE_TILE) * CACHE_TILE
+
+
 def init_kv_cache(batch: int, n_kv: int, cap: int, head_dim: int,
                   dtype) -> KVCache:
-    z = jnp.zeros((batch, n_kv, cap, head_dim), dtype)
+    """A cache of `cap` positions, made `cache_len(cap)` long."""
+    z = jnp.zeros((batch, n_kv, cache_len(cap), head_dim), dtype)
     return KVCache(z, z)
 
 
@@ -77,24 +96,26 @@ def _project_qkv(p, x, positions, *, theta, rotary_dim, mrope_sections):
     return q, k, v
 
 
-def _sdpa(q, k, v, mask):
-    """q: [B,S,H,Dh], k/v: [B,T,Hkv,Dh], mask: broadcastable [B,1,S,T]."""
-    hq, hkv = q.shape[2], k.shape[2]
+def _sdpa(q, k, v, mask, kv: str = "bthe"):
+    """q: [B,S,H,Dh], k/v: [B,T,Hkv,Dh], mask: broadcastable [B,1,S,T].
+    kv="bhte": k/v are [B,Hkv,T,Dh], a cache as it is stored, read as it
+    lies (query heads grouped onto their kv head), never transposed."""
+    hq, hkv = q.shape[2], k.shape[kv.index("h")]
     scale = 1.0 / np.sqrt(q.shape[-1])
     if hq != hkv:
         g = hq // hkv
         qg = q.reshape(q.shape[0], q.shape[1], hkv, g, q.shape[3])
-        logits = jnp.einsum("bshge,bthe->bhgst", qg, k) * scale
+        logits = jnp.einsum(f"bshge,{kv}->bhgst", qg, k) * scale
         if mask is not None:
             logits = jnp.where(mask[:, :, None], logits, NEG_INF)
         probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-        out = jnp.einsum("bhgst,bthe->bshge", probs.astype(v.dtype), v)
+        out = jnp.einsum(f"bhgst,{kv}->bshge", probs.astype(v.dtype), v)
         return out.reshape(q.shape)
-    logits = jnp.einsum("bshe,bthe->bhst", q, k) * scale
+    logits = jnp.einsum(f"bshe,{kv}->bhst", q, k) * scale
     if mask is not None:
         logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    return jnp.einsum("bhst,bthe->bshe", probs.astype(v.dtype), v)
+    return jnp.einsum(f"bhst,{kv}->bshe", probs.astype(v.dtype), v)
 
 
 def chunked_attention(q, k, v, *, window=-1, chunk: int = 1024,
@@ -178,29 +199,64 @@ def causal_mask(sq: int, sk: int, *, window=-1, offset: int = 0):
     return m[None, None]
 
 
-def _kv_write(cache: KVCache, k, v, pos):
-    """k, v [B,S,Hkv,dh] written into the cache at sequence position `pos`,
-    under the `kv_write` scope that the device trace names them by."""
+def cache_put(buf, new, pos, layer=None):
+    """`new` written into the cache buffer `buf` at sequence offset `pos`
+    (axis -2 of both); into its `layer` slice when `buf` is stacked
+    [L, ...].  Only the rows written (for one token, its tile of
+    `CACHE_TILE` rows, so `buf` is `cache_len` long) are touched, so a
+    carried or donated buffer changes in place."""
+    new = new.astype(buf.dtype)
+    if layer is not None:
+        new = new[None]
+    if new.shape[-2] == 1:
+        t = buf.shape[-2]
+        assert t % CACHE_TILE == 0, f"{t} positions are not whole tiles"
+        at = pos - pos % CACHE_TILE
+        old = jax.lax.dynamic_slice(
+            buf, _start(buf.ndim, at, layer),
+            new.shape[:-2] + (CACHE_TILE,) + new.shape[-1:])
+        hit = (at + jnp.arange(CACHE_TILE) == pos)[:, None]
+        new, pos = jnp.where(hit, new, old), at
+    return jax.lax.dynamic_update_slice(buf, new, _start(buf.ndim, pos,
+                                                         layer))
+
+
+def _start(ndim, pos, layer):
+    start = [0] * ndim
+    start[-2] = pos
+    if layer is not None:
+        start[0] = layer
+    return start
+
+
+def layer_of(buf, layer=None):
+    """`buf`, or its `layer` slice when it is stacked [L, ...]."""
+    if layer is None:
+        return buf
+    return jax.lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
+
+
+def _kv_write(cache: KVCache, k, v, pos, layer=None) -> KVCache:
+    """k, v [B,S,Hkv,dh] written into the cache at sequence position `pos`
+    (and `layer`, see `cache_put`), under the `kv_write` scope that the
+    device trace names them by."""
     with jax.named_scope("kv_write"):
-        kc = jax.lax.dynamic_update_slice(
-            cache.k, k.transpose(0, 2, 1, 3).astype(cache.k.dtype),
-            (0, 0, pos, 0))
-        vc = jax.lax.dynamic_update_slice(
-            cache.v, v.transpose(0, 2, 1, 3).astype(cache.v.dtype),
-            (0, 0, pos, 0))
-    return kc, vc
+        return KVCache(cache_put(cache.k, k.transpose(0, 2, 1, 3), pos, layer),
+                       cache_put(cache.v, v.transpose(0, 2, 1, 3), pos, layer))
 
 
 def attention(p, x, positions, *, theta: float = 10000.0,
               rotary_dim: int | None = None, window: int = -1,
               mrope_sections=None, cache: KVCache | None = None,
-              cache_pos=None):
+              cache_pos=None, layer=None):
     """Self-attention.
 
     Train / no-cache: full causal (+window) attention over x.
     Prefill: cache provided, cache_pos None -> bulk-write k/v at [0, S).
     Decode: cache provided, cache_pos scalar -> write at cache_pos, attend
-            over cache[<=cache_pos] (with optional window).
+            over cache[<=cache_pos] (with optional window).  With `layer`
+            the cache is the stacked [L, ...] cache of every layer: the new
+            k/v go into slice `layer` in place and that slice is read.
     Returns (y, new_cache).
     """
     b, s, _ = x.shape
@@ -235,24 +291,21 @@ def attention(p, x, positions, *, theta: float = 10000.0,
             out = _sdpa(q, k, v, causal_mask(s, s, window=window))
         new_cache = None
     elif cache_pos is None:  # prefill
-        kc, vc = _kv_write(cache, k, v, 0)
+        new_cache = _kv_write(cache, k, v, 0)
         if s >= CHUNKED_THRESHOLD:
             out = chunked_attention(q, k, v, window=window, chunk=CHUNK)
         else:
             out = _sdpa(q, k, v, causal_mask(s, s, window=window))
-        new_cache = KVCache(kc, vc)
     else:  # decode: s == 1
-        cap = cache.k.shape[2]
         pos = jnp.asarray(cache_pos, jnp.int32)
-        kc, vc = _kv_write(cache, k, v, pos)
-        ki = jnp.arange(cap)
+        new_cache = _kv_write(cache, k, v, pos, layer)
+        ki = jnp.arange(cache.k.shape[-2])
         w = jnp.asarray(window, jnp.int32)
         w_eff = jnp.where(w > 0, w, jnp.int32(2**30))
-        m = (ki <= pos) & (ki > pos - w_eff)
-        mask = m[None, None, None, :]
-        out = _sdpa(q, kc.transpose(0, 2, 1, 3), vc.transpose(0, 2, 1, 3),
-                    mask)
-        new_cache = KVCache(kc, vc)
+        mask = (ki <= pos) & (ki > pos - w_eff)
+        out = _sdpa(q, layer_of(new_cache.k, layer),
+                    layer_of(new_cache.v, layer), mask[None, None, None],
+                    kv="bhte")
     if seq_parallel:
         out = constrain(out, BATCH, MODEL)
     else:

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .attention import NEG_INF
+from .attention import NEG_INF, cache_len, cache_put, layer_of
 from .common import apply_rope, rms_norm, rope_frequencies
 
 
@@ -50,8 +50,10 @@ def init_mla(cfg, key) -> dict:
 
 
 def init_mla_cache(cfg, batch: int, cap: int, dtype) -> MLACache:
+    """A latent cache of `cap` positions, made `cache_len(cap)` long."""
     return MLACache(jnp.zeros(
-        (batch, cap, cfg.kv_lora_rank + cfg.qk_rope_head_dim), dtype))
+        (batch, cache_len(cap), cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+        dtype))
 
 
 def _inv_freq(cfg):
@@ -61,18 +63,18 @@ def _inv_freq(cfg):
     return jnp.asarray(cfg.rope_yarn.inv_freq(rope, cfg.rope_theta))
 
 
-def _ckv_write(ckv, new, pos):
+def _ckv_write(ckv, new, pos, layer=None):
     with jax.named_scope("kv_write"):
-        return jax.lax.dynamic_update_slice(ckv, new.astype(ckv.dtype),
-                                            (0, pos, 0))
+        return cache_put(ckv, new, pos, layer)
 
 
 def mla_attention(cfg, p, x, positions, *, cache: MLACache | None = None,
-                  cache_pos=None):
+                  cache_pos=None, layer=None):
     """Latent self-attention.  Train: causal over x.  Prefill (cache given,
     cache_pos None): writes the latent cache at [0, S).  Decode (cache_pos
     scalar, S == 1): writes at cache_pos and attends over cache[<= pos] in
-    the absorbed form.  Returns (y, new_cache)."""
+    the absorbed form; with `layer` the cache is stacked [L, ...] and only
+    its slice `layer` is written and read.  Returns (y, new_cache)."""
     b, s, _ = x.shape
     nope, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
     inv = _inv_freq(cfg)
@@ -103,8 +105,8 @@ def mla_attention(cfg, p, x, positions, *, cache: MLACache | None = None,
         out = jnp.einsum("bhst,bthv->bshv", probs.astype(v.dtype), v)
     else:
         pos = jnp.asarray(cache_pos, jnp.int32)
-        ckv_all = _ckv_write(cache.ckv, new, pos)
-        new_cache = MLACache(ckv_all)
+        new_cache = MLACache(_ckv_write(cache.ckv, new, pos, layer))
+        ckv_all = layer_of(new_cache.ckv, layer)
         with jax.named_scope("mla_absorb"):
             q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, p["wk_b"])
             qf = jnp.concatenate([q_lat, q_pe.astype(q_lat.dtype)], axis=-1)

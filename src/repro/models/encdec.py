@@ -156,11 +156,13 @@ def init_cache(cfg: ArchConfig, batch: int, cap: int, frames: int,
                dtype=jnp.bfloat16) -> EncDecCache:
     stack = lambda leaf: jnp.broadcast_to(leaf[None],
                                           (cfg.n_layers,) + leaf.shape)
+    # the encoder's K/V, written whole at prefill and attended unmasked, so
+    # exactly `frames` long (not rounded as the decoder's self-cache is)
+    z = jnp.zeros((batch, cfg.n_kv_heads, frames, cfg.head_dim), dtype)
     return EncDecCache(
         self_kv=jax.tree.map(stack, init_kv_cache(
             batch, cfg.n_kv_heads, cap, cfg.head_dim, dtype)),
-        cross_kv=jax.tree.map(stack, init_kv_cache(
-            batch, cfg.n_kv_heads, frames, cfg.head_dim, dtype)),
+        cross_kv=jax.tree.map(stack, KVCache(z, z)),
     )
 
 

@@ -98,27 +98,29 @@ def init_moe_block(cfg: ArchConfig, key) -> dict:
 
 
 def _attention_block(cfg: ArchConfig, bp, x, positions, window, theta,
-                     cache: KVCache | None, cache_pos):
+                     cache: KVCache | None, cache_pos, layer=None):
     """Pre-norm self-attention with its residual, under the `attention`
-    scope that the device trace names its operations by."""
+    scope that the device trace names its operations by.  `layer`: the
+    cache is the scan's stacked cache, written and read at that layer."""
     with jax.named_scope("attention"):
         h = rms_norm(bp["ln1"], x)
         if cfg.mla:
             att, new_cache = mla_attention(cfg, bp["attn"], h, positions,
-                                           cache=cache, cache_pos=cache_pos)
+                                           cache=cache, cache_pos=cache_pos,
+                                           layer=layer)
         else:
             att, new_cache = attention(
                 bp["attn"], h, positions, theta=theta,
                 rotary_dim=_rotary_dim(cfg), window=window,
                 mrope_sections=cfg.mrope_sections, cache=cache,
-                cache_pos=cache_pos)
+                cache_pos=cache_pos, layer=layer)
         return x + att, new_cache
 
 
 def apply_dense_block(cfg: ArchConfig, bp, x, positions, window, theta,
-                      cache: KVCache | None, cache_pos):
+                      cache: KVCache | None, cache_pos, layer=None):
     x, new_cache = _attention_block(cfg, bp, x, positions, window, theta,
-                                    cache, cache_pos)
+                                    cache, cache_pos, layer)
     with jax.named_scope("mlp"):
         h = rms_norm(bp["ln2"], x)
         x = x + swiglu(bp["mlp"], h)
@@ -126,9 +128,9 @@ def apply_dense_block(cfg: ArchConfig, bp, x, positions, window, theta,
 
 
 def apply_moe_block(cfg: ArchConfig, bp, x, positions, window, theta,
-                    cache: KVCache | None, cache_pos):
+                    cache: KVCache | None, cache_pos, layer=None):
     x, new_cache = _attention_block(cfg, bp, x, positions, window, theta,
-                                    cache, cache_pos)
+                                    cache, cache_pos, layer)
     with jax.named_scope("mlp"):
         h = rms_norm(bp["ln2"], x)
         if cfg.experts_held:
@@ -188,21 +190,21 @@ def init_unit_cache(cfg: ArchConfig, batch: int, cap: int, dtype) -> Any:
 
 
 def apply_unit(cfg: ArchConfig, up, x, positions, window, theta, cache,
-               cache_pos):
+               cache_pos, layer=None):
     if cfg.family == "moe" and cfg.moe_every == 2:
         c_d = cache["dense"] if cache is not None else None
         c_m = cache["moe"] if cache is not None else None
         x, nc_d, _ = apply_dense_block(cfg, up["dense"], x, positions, window,
-                                       theta, c_d, cache_pos)
+                                       theta, c_d, cache_pos, layer)
         x, nc_m, aux = apply_moe_block(cfg, up["moe"], x, positions, window,
-                                       theta, c_m, cache_pos)
+                                       theta, c_m, cache_pos, layer)
         new_cache = None if nc_d is None else {"dense": nc_d, "moe": nc_m}
         return x, new_cache, aux
     if cfg.family == "moe":
         return apply_moe_block(cfg, up, x, positions, window, theta, cache,
-                               cache_pos)
+                               cache_pos, layer)
     return apply_dense_block(cfg, up, x, positions, window, theta, cache,
-                             cache_pos)
+                             cache_pos, layer)
 
 
 # ---------------------------------------------------------------------------
@@ -246,26 +248,36 @@ def _embed_inputs(cfg: ArchConfig, params, tokens, vision_embeds):
 
 def _scan_layers(cfg: ArchConfig, apply, stacked, x, positions, cache,
                  cache_pos, aux0):
-    """Scan `apply` over stacked per-layer params (and cache, or None)."""
-    windows, thetas = layer_schedule(cfg, jax.tree.leaves(stacked)[0].shape[0])
+    """Scan `apply` over stacked per-layer params and the stacked cache.
+
+    Training and prefill take each layer's cache (or None) as a scan input
+    and stack the new ones as outputs.  Decode (`cache_pos` given) carries
+    the stacked cache instead: each layer writes its new token into that
+    one buffer at its own index, so no layer's cache is copied out and back
+    (a donated cache is then updated in place)."""
+    n = jax.tree.leaves(stacked)[0].shape[0]
+    windows, thetas = layer_schedule(cfg, n)
+    decode = cache_pos is not None
 
     def body(carry, xs):
-        xc, aux = carry
-        if cache is None:
-            up, w, th = xs
-            c = None
-        else:
-            up, w, th, c = xs
-        xc, new_c, a = apply(cfg, up, xc, positions, w, th, c, cache_pos)
-        return (xc, jax.tree.map(jnp.add, aux, a)), new_c
+        xc, aux, carried = carry
+        up, w, th, i, c = xs
+        xc, new_c, a = apply(cfg, up, xc, positions, w, th,
+                             carried if decode else c, cache_pos,
+                             i if decode else None)
+        aux = jax.tree.map(jnp.add, aux, a)
+        if decode:
+            return (xc, aux, new_c), None
+        return (xc, aux, None), new_c
 
     from repro.layers.common import apply_remat
     body = apply_remat(body, cfg.remat)
-    xs = (stacked, windows, thetas) if cache is None else \
-        (stacked, windows, thetas, cache)
-    (x, aux), new_cache = jax.lax.scan(body, (x, aux0), xs,
-                                       unroll=cfg.scan_unroll)
-    return x, aux, new_cache
+    xs = (stacked, windows, thetas, jnp.arange(n, dtype=jnp.int32),
+          None if decode else cache)
+    (x, aux, carried), ys = jax.lax.scan(
+        body, (x, aux0, cache if decode else None), xs,
+        unroll=cfg.scan_unroll)
+    return x, aux, carried if decode else ys
 
 
 def _run_units(cfg: ArchConfig, params, x, positions, cache, cache_pos):

@@ -7,7 +7,9 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.layers.attention import _sdpa, causal_mask, chunked_attention
+from repro.layers.attention import (CACHE_TILE, _sdpa, cache_len, cache_put,
+                                   causal_mask, chunked_attention,
+                                   init_kv_cache)
 
 
 def _qkv(rng, b, sq, sk, hq, hkv, dh, dtype=jnp.float32):
@@ -84,3 +86,36 @@ def test_bf16_stability(rng):
     out = chunked_attention(q, k, v, chunk=32)
     assert out.dtype == jnp.bfloat16
     assert np.isfinite(np.asarray(out, np.float32)).all()
+
+
+@pytest.mark.parametrize("layer", [None, 2])
+@pytest.mark.parametrize("t,pos", [(128, 0), (128, 5), (128, 127),
+                                   (256, 128), (256, 200), (256, 255),
+                                   (384, 0), (384, 255), (384, 256),
+                                   (384, 383), (1152, 1148)])
+def test_cache_put_writes_one_row_and_nothing_else(rng, layer, t, pos):
+    """A one-token write into a `t`-position cache (one tile or several),
+    flat or stacked by layer, changes that one position of that one layer
+    and no other number; jitted, with a traced position, as the decode
+    scan calls it."""
+    shape = (2, 3, t, 4) if layer is None else (4, 2, 3, t, 4)
+    buf = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    new = jnp.asarray(rng.normal(size=(2, 3, 1, 4)), jnp.float32)
+    got = jax.jit(cache_put)(buf, new, jnp.int32(pos),
+                             None if layer is None else jnp.int32(layer))
+    want = np.array(buf)
+    if layer is None:
+        want[:, :, pos] = np.asarray(new)[:, :, 0]
+    else:
+        want[layer, :, :, pos] = np.asarray(new)[:, :, 0]
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("cap,length", [(1, 128), (30, 128), (128, 128),
+                                        (129, 256), (1149, 1152)])
+def test_a_cache_is_made_whole_tiles_long(cap, length):
+    """Every attention cache, and so every caller's, holds `cap` positions
+    rounded up to whole tiles, where a one-token write finds its tile."""
+    assert cache_len(cap) == length and length % CACHE_TILE == 0
+    cache = init_kv_cache(2, 3, cap, 4, jnp.float32)
+    assert cache.k.shape == cache.v.shape == (2, 3, length, 4)

@@ -1,4 +1,5 @@
-"""The main-path Pallas kernels compile for a TPU v5e at real widths.
+"""The main-path Pallas kernels compile for a TPU v5e at real widths, and
+the serve decode step compiles there without copying its cache.
 
 Mosaic refuses what interpret mode accepts (value-level dynamic slices,
 blocks off the (8, 128) tiling, VMEM or SMEM beyond the chip), so each
@@ -8,15 +9,21 @@ Nothing runs: these tests say nothing about results or times.
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library.
 """
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import ARCHS
 from repro.core.fabric import FabricView
 from repro.kernels.fabric_egress import fabric_egress_pallas
 from repro.kernels.memcrypt import checked_memcrypt_view_pallas, memcrypt_pallas
 from repro.kernels.permcheck import ShardView, permcheck_view_pallas
+from repro.launch.serve import ServeEngine
+from repro.models import registry
 
 
 @pytest.fixture(scope="module")
@@ -108,3 +115,54 @@ def test_checked_memcrypt_view_compiles(spec):
 def test_memcrypt_compiles(spec):
     _compiles(lambda d: memcrypt_pallas(d, key0=1, key1=2, interpret=False),
               spec((1 << 20,), jnp.uint32))
+
+
+def _copies(hlo: str) -> list[tuple[bool, tuple]]:
+    """(inside the body of a while loop, dimensions less leading ones) of
+    each copy in `hlo`."""
+    bodies = set(re.findall(r"while\(.*?body=(%[\w.\-]+)", hlo))
+    found, comp = [], None
+    for line in hlo.splitlines():
+        if line and not line.startswith(" "):
+            comp = line.split(" ")[0]
+        m = re.match(r"\s+(?:ROOT )?\S+ = \w+\[([\d,]*)\]\S* copy\(", line)
+        if m:
+            dims = [int(d) for d in filter(None, m.group(1).split(","))]
+            while dims and dims[0] == 1:
+                dims.pop(0)
+            found.append((comp in bodies, tuple(dims)))
+    return found
+
+
+@pytest.mark.parametrize("arch_id", [
+    "qwen1.5-0.5b",         # 64-wide heads: the cache lies positions-minor
+    "glm4-9b",              # GQA
+    "deepseek-v2-lite",     # latent cache, a lead layer ahead of the loop
+])
+def test_the_serve_decode_updates_its_cache_in_place(spec, arch_id):
+    """The engine's decode step at published widths (4 scanned layers, a
+    small vocabulary and expert count, batch 8, 512 positions): the cache
+    is aliased to the output, the layer loop copies no layer's cache, and
+    no stacked cache is copied whole: no layer is re-laid-out around its
+    write, inside the loop or out of it."""
+    full = ARCHS[arch_id]
+    cfg = dataclasses.replace(
+        full, n_layers=4 + full.first_k_dense, vocab=1024,
+        n_experts=min(full.n_experts, 8),
+        experts_held=full.experts_held and (0, min(full.n_experts, 8)))
+    b, cap = 8, 512
+    on_chip = lambda t: jax.tree.map(lambda x: spec(x.shape, x.dtype), t)
+    cache = on_chip(registry.cache_shapes(cfg, b, cap, cfg.pdtype))
+    args = [on_chip(registry.param_shapes(cfg)), cache,
+            spec((b, 1), jnp.int32), spec((), jnp.int32)]
+    if cfg.experts_held:
+        args.append(spec((2,), jnp.int32))
+    compiled = ServeEngine(cfg, None, batch=b, cap=cap)._decode.lower(
+        *args).compile()
+    leaves = jax.tree.leaves(cache)
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        x.size * x.dtype.itemsize for x in leaves)
+    stacked = {x.shape for x in leaves if x.shape[0] > 1}
+    layers = {x.shape[1:] for x in leaves} | stacked
+    assert [d for in_loop, d in _copies(compiled.as_text())
+            if d in (layers if in_loop else stacked)] == []

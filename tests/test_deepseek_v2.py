@@ -10,6 +10,8 @@ import pytest
 from bench.drivers import serve_dsv2
 from bench.reference import dsv2_ref
 from repro.configs import ARCHS, smoke_config
+from repro.launch.serve import ServeEngine
+from repro.layers.attention import cache_len
 from repro.layers.common import rms_norm, swiglu
 from repro.layers.mla import init_mla, mla_attention
 from repro.layers.moe import init_moe, moe_ffn_held
@@ -53,7 +55,8 @@ def _tokens(b, s, seed=0):
 def test_published_config_and_latent_cache_shape():
     """The registered config has the published widths, and its serving
     cache holds 576 numbers a token a layer (512 latent + 64 rope key) in
-    bf16, for the dense layer and the 26 routed layers."""
+    bf16, for the dense layer and the 26 routed layers, over the asked
+    positions rounded up to whole 128-position tiles."""
     c = ARCHS["deepseek-v2-lite"]
     assert (c.n_layers, c.d_model, c.n_heads, c.d_ff, c.vocab,
             c.kv_lora_rank, c.qk_nope_head_dim, c.qk_rope_head_dim,
@@ -65,8 +68,8 @@ def test_published_config_and_latent_cache_shape():
                ** 2) < 1e-12 and c.rope_yarn.cos_scale == 1.0
     assert 15.6e9 < c.n_params() < 15.8e9
     cache = registry.cache_shapes(c, batch=32, cap=1149)
-    assert cache["lead"].ckv.shape == (1, 32, 1149, 576)
-    assert cache["units"].ckv.shape == (26, 32, 1149, 576)
+    assert cache["lead"].ckv.shape == (1, 32, 1152, 576)
+    assert cache["units"].ckv.shape == (26, 32, 1152, 576)
     assert all(x.dtype == jnp.bfloat16 for x in jax.tree.leaves(cache))
 
 
@@ -106,6 +109,32 @@ def test_prefill_then_decode_matches_the_reference():
         dsv2_ref.held_slots(cfg, w, toks[:, :9])
 
 
+def test_the_engine_serves_the_reference_tokens_from_a_donated_cache():
+    """`ServeEngine` over the latent cache, a group of two prompts and then
+    a group of one: every decode launch consumes its cache in place
+    (`cache_donations == steps` after every tick), and every served token
+    is the reference's best at its position, teacher-forced over the
+    served tokens, as the benchmark's comparison judges it."""
+    cfg = _cfg()
+    arch, params = _program(cfg)
+    plen, gen = 9, 4
+    e = ServeEngine(arch, params, batch=2, cap=plen + gen)
+    t = e.add_tenant("a", host_id=0)
+    for p in _tokens(3, plen, seed=5):
+        e.submit("a", p)
+    while e.has_work():
+        e.step(gen=gen)
+        assert e.cache_donations == e.steps
+    assert e.steps == 2 * gen and len(t.done) == 3 and not t.aborted
+    toks = np.stack([np.concatenate([p, np.asarray(g, np.int32)])
+                     for p, g in t.done])
+    ref = np.asarray(dsv2_ref.logits(cfg, dsv2_ref.make_weights(cfg, SEED),
+                                     toks[:, :-1], plen - 1))
+    served = toks[:, plen:]
+    gap = ref.max(-1) - np.take_along_axis(ref, served[..., None], -1)[..., 0]
+    assert gap.max() < 1e-3 * ref.std()
+
+
 def test_absorbed_decode_equals_decompressed_attention():
     """Decode in the absorbed form, over the latent cache, gives what the
     decompressed causal attention gives at the last position."""
@@ -122,7 +151,7 @@ def test_absorbed_decode_equals_decompressed_attention():
     np.testing.assert_allclose(np.asarray(last[:, 0]),
                                np.asarray(full[:, -1]), rtol=1e-4,
                                atol=1e-5)
-    assert cache.ckv.shape == (b, s, arch.kv_lora_rank
+    assert cache.ckv.shape == (b, cache_len(s), arch.kv_lora_rank
                                + arch.qk_rope_head_dim)
 
 

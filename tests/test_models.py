@@ -2,6 +2,7 @@
 forward/train step on CPU asserting shapes + no NaNs, and the serving path
 (prefill + decode) is consistent with the training-time forward."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -122,6 +123,49 @@ def test_prefill_decode_matches_forward(smoke_state, arch_id):
     np.testing.assert_allclose(
         np.asarray(logits_d[:, 0], np.float32),
         np.asarray(full_logits[:, s - 1], np.float32), rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("arch_id", [
+    "qwen1.5-0.5b",                 # MHA
+    "glm4-9b",                      # GQA, 2 kv heads for 4
+    "gemma3-1b",                    # sliding window 16, passed by decode
+    "llama4-maverick-400b-a17b",    # dense + MoE pair units
+    "deepseek-v2-lite",             # latent cache, a lead layer ahead
+    "zamba2-1.2b",                  # shared attention over mamba groups
+    "seamless-m4t-medium",          # decoder self-cache beside cross K/V
+])
+def test_decode_steps_through_the_carried_cache_match_forward(smoke_state,
+                                                              arch_id):
+    """Eight decode steps, each writing its token's cache tile in place
+    (the lm families carry the stacked cache through the layer scan; all
+    are donated to the jit), give the training forward's logits at every
+    position, and leave the cache that a prefill of the whole sequence
+    writes (the positions past it untouched)."""
+    cfg, params = smoke_state(arch_id)
+    if cfg.family == "moe":
+        cfg = dataclasses.replace(cfg, capacity_factor=float(cfg.n_experts))
+    b, s, p0, cap = 2, 24, 16, 30
+    batch = _batch(cfg, b=b, s=s, seed=2)
+    tokens = batch["tokens"]
+    kwargs = {"frames": batch["frames"]} if cfg.family == "encdec" else {}
+    mod = registry.model_module(cfg)
+    full, _ = mod.forward(cfg, params, tokens, **kwargs)
+    _, cache = mod.prefill(cfg, params, tokens[:, :p0],
+                           cache_dtype=jnp.float32, cap=cap, **kwargs)
+    decode = jax.jit(functools.partial(mod.decode_step, cfg),
+                     donate_argnums=(1,))
+    for pos in range(p0, s):
+        logits, cache = decode(params, cache, tokens[:, pos:pos + 1],
+                               jnp.asarray(pos, jnp.int32))
+        np.testing.assert_allclose(
+            np.asarray(logits[:, 0]), np.asarray(full[:, pos], np.float32),
+            rtol=2e-2, atol=2e-2, err_msg=f"{arch_id} at {pos}")
+    _, want = mod.prefill(cfg, params, tokens, cache_dtype=jnp.float32,
+                          cap=cap, **kwargs)
+    assert jax.tree.structure(cache) == jax.tree.structure(want)
+    for got, ref in zip(jax.tree.leaves(cache), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=2e-2, atol=2e-2)
 
 
 @pytest.mark.parametrize("arch_id", ARCH_IDS)

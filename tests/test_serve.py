@@ -8,7 +8,8 @@ import pytest
 
 from repro.configs import ARCHS, smoke_config
 from repro.launch.serve import ServeEngine
-from repro.models import registry
+from repro.layers.common import rms_norm, unembed
+from repro.models import lm, registry
 
 PLEN, GEN = 10, 4
 
@@ -134,3 +135,73 @@ def test_a_tenant_revoked_mid_group_serves_nothing_from_its_deny(model):
     assert [list(p) for p in a.aborted] == [list(p) for p in pa]
     assert not a.done and a.out == a_out
     assert [g for _, g in c.done] == _greedy(cfg, params, pc, 2, GEN)
+
+
+def test_every_decode_step_consumes_its_cache_in_place(model):
+    """Each decode launch is given the tenant's cache to update in place:
+    after every tick the launches that consumed their input cache equal
+    the decode steps, the old cache's buffers are gone, and the served
+    tokens are still the greedy tokens."""
+    cfg, params = model
+    e = ServeEngine(cfg, params, batch=2, cap=PLEN + GEN)
+    pa, pb = _prompts(cfg, 10, 2), _prompts(cfg, 11, 2)
+    for name, host, prompts in (("a", 0, pa), ("b", 1, pb)):
+        e.add_tenant(name, host_id=host)
+        for p in prompts:
+            e.submit(name, p)
+    ticks = 0
+    while e.has_work():
+        before = {n: t.cache for n, t in e.tenants.items()
+                  if t.group is not None}
+        e.step(gen=GEN)
+        ticks += 1
+        assert e.cache_donations == e.steps
+        assert all(leaf.is_deleted() for c in before.values()
+                   for leaf in jax.tree.leaves(c))
+    assert ticks == GEN and e.steps == 2 * GEN
+    assert [g for _, g in e.tenants["a"].done] == _greedy(cfg, params, pa,
+                                                           2, GEN)
+    assert [g for _, g in e.tenants["b"].done] == _greedy(cfg, params, pb,
+                                                           2, GEN)
+
+
+def _decode_through_xs(cfg, params, cache, tokens, pos):
+    """The decode step as the layer scan ran it before the cache was
+    carried: each layer's cache a scan input, its new cache a scan
+    output."""
+    x = lm._embed_inputs(cfg, params, tokens, None)
+    positions = jnp.broadcast_to(pos, tokens.shape)
+    windows, thetas = lm.layer_schedule(cfg, lm.n_units(cfg))
+
+    def body(x, xs):
+        up, w, th, c = xs
+        x, c, _ = lm.apply_unit(cfg, up, x, positions, w, th, c, pos)
+        return x, c
+    x, cache = jax.lax.scan(body, x, (params["units"], windows, thetas,
+                                      cache))
+    x = rms_norm(params["final_norm"], x)
+    return unembed(params["embed"], params["head"], x,
+                   tied=cfg.tie_embeddings), cache
+
+
+def test_the_compiled_decode_updates_the_cache_without_a_copy(model):
+    """Compiled at test size: the serve decode aliases its whole cache
+    input to its output, and needs at least one cache fewer bytes of
+    temporaries than the scan that passed each layer's cache in and out,
+    donated alike, which copies the stacked cache into a new one."""
+    cfg, _ = model
+    b, cap = 4, 256
+    args = (registry.param_shapes(cfg),
+            registry.cache_shapes(cfg, b, cap, cfg.pdtype),
+            jax.ShapeDtypeStruct((b, 1), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32))
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(args[1]))
+    engine = ServeEngine(cfg, None, batch=b, cap=cap)
+    carried = engine._decode.lower(*args).compile().memory_analysis()
+    through_xs = jax.jit(functools.partial(_decode_through_xs, cfg),
+                         donate_argnums=(1,)).lower(*args).compile() \
+        .memory_analysis()
+    assert carried.alias_size_in_bytes >= cache_bytes
+    assert carried.temp_size_in_bytes + cache_bytes <= \
+        through_xs.temp_size_in_bytes
